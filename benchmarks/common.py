@@ -15,42 +15,40 @@ RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
 # ---------------------------------------------------------------------------
 # Per-device-kind peak table — the ONE copy of the hardware constants shared
 # by the roofline analysis AND the roofline CI gate (benchmarks/roofline.py).
-# Keys follow jax's device_kind strings. Values per chip:
+# Keys are the device_kind strings jax reports (a TPU v5e chip reports
+# "TPU v5 lite"). Values per chip:
 #   peak_flops   bf16 MXU peak (FLOP/s)
 #   peak_int8    int8 MXU peak (OP/s) — the serving kernels' compute roof
 #   hbm_bw       HBM bandwidth (byte/s)
 #   ici_bw       ICI bandwidth per link (byte/s)
+# Source for TPU v5e: Google Cloud documentation, "TPU v5e" (197 TFLOP/s
+# bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect over 4 links).
 # ---------------------------------------------------------------------------
 
+REFERENCE_KIND = "TPU v5 lite"
+
 DEVICE_PEAKS = {
-    "TPU v4":  {"peak_flops": 275e12, "peak_int8": 275e12,
-                "hbm_bw": 1228e9, "ici_bw": 50e9},
-    "TPU v5e": {"peak_flops": 197e12, "peak_int8": 394e12,
-                "hbm_bw": 819e9, "ici_bw": 50e9},
-    "TPU v5p": {"peak_flops": 459e12, "peak_int8": 918e12,
-                "hbm_bw": 2765e9, "ici_bw": 100e9},
-    "TPU v6e": {"peak_flops": 918e12, "peak_int8": 1836e12,
-                "hbm_bw": 1640e9, "ici_bw": 100e9},
+    "TPU v5 lite": {"peak_flops": 197e12, "peak_int8": 393e12,
+                    "hbm_bw": 819e9, "ici_bw": 50e9},
     # interpret-mode hosts: placeholder roof so the analysis stays runnable
     # off-TPU (the CI gate never applies timing thresholds on these)
-    "cpu":     {"peak_flops": 1e12, "peak_int8": 2e12,
-                "hbm_bw": 100e9, "ici_bw": 10e9},
+    "cpu": {"peak_flops": 1e12, "peak_int8": 2e12,
+            "hbm_bw": 100e9, "ici_bw": 10e9},
 }
 
 
 def device_peaks(kind: str | None = None) -> dict:
-    """Peaks for ``kind`` (default: the host's first device). Unknown kinds
-    fall back to TPU v5e — the repo's reference part — with a note so the
-    analysis is visibly approximate rather than silently wrong."""
+    """Peaks for ``kind`` (default: the host's first device). A kind the
+    table does not hold is an error: a roofline share priced against some
+    other chip's peaks would be wrong without saying so."""
     if kind is None:
-        try:
-            kind = jax.devices()[0].device_kind
-        except Exception:
-            kind = "cpu"
-    if kind in DEVICE_PEAKS:
-        return {"device_kind": kind, **DEVICE_PEAKS[kind]}
-    base = "cpu" if kind.lower() in ("cpu", "gpu") else "TPU v5e"
-    return {"device_kind": kind, "assumed": base, **DEVICE_PEAKS[base]}
+        kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"add it to benchmarks.common.DEVICE_PEAKS "
+                       f"(have {sorted(DEVICE_PEAKS)})")
+    return {"device_kind": kind, **DEVICE_PEAKS[kind]}
 
 
 def emit(name: str, us_per_call: float, derived: str = "") -> None:

@@ -32,8 +32,8 @@ import math
 import os
 import time
 
-from benchmarks.common import (RESULTS_DIR, DEVICE_PEAKS, device_peaks,
-                               emit, save_json, time_call)
+from benchmarks.common import (RESULTS_DIR, DEVICE_PEAKS, REFERENCE_KIND,
+                               device_peaks, emit, save_json, time_call)
 
 # Stated minimum fraction of the device's int8 MXU peak each Pallas backend
 # must achieve on the gate's prefill-shaped projection (m=512, k=n=1024).
@@ -87,7 +87,7 @@ def gate_thresholds() -> dict:
 def analyze_record(r: dict, peaks: dict | None = None) -> dict | None:
     # dry-run artifacts are produced against the repo's reference part;
     # pass peaks= to re-price them for another device kind
-    pk = peaks or device_peaks("TPU v5e")
+    pk = peaks or device_peaks(REFERENCE_KIND)
     if r.get("skipped"):
         return {"arch": r["arch"], "shape": r["shape"],
                 "skipped": r["skipped"]}

@@ -37,7 +37,7 @@ def main(argv=None):
     reqs = summary["requests"]
     assert len(reqs) == n_requests
     for r in reqs:
-        assert len(r["sample"]) == min(8, args.gen)
+        assert len(r["token_ids"]) == args.gen
         for key in ("rung_bits", "b_x_tilde", "r", "tokens",
                     "est_bitflips_per_token", "est_bitflips_total"):
             assert key in r, key

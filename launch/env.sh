@@ -5,28 +5,9 @@
 #         --backend packed --autotune ...
 #
 # Every knob is set with ${VAR:-default} so an explicitly exported value
-# always wins. The XLA/libtpu flags are only exported when a TPU chip is
-# actually attached: XLA's flag parser ABORTS the process on flags its
-# build didn't register, so sourcing TPU flags on a CPU host would kill
-# every jax program rather than being ignored.
-
-# --- XLA / libtpu (TPU hosts only) -----------------------------------------
-if ls /dev/accel* > /dev/null 2>&1 || [ -d /dev/vfio ] \
-        || [ -n "${TPU_NAME:-}" ]; then
-    # Decode is latency-bound: async collectives + latency-hiding scheduler
-    # let the per-layer all-reduce of the Megatron column/row pair overlap
-    # the next projection's compute instead of serializing after it.
-    _PANN_XLA_FLAGS="--xla_tpu_enable_async_collective_fusion=true"
-    _PANN_XLA_FLAGS="${_PANN_XLA_FLAGS} --xla_tpu_enable_async_collective_fusion_fuse_all_gather=true"
-    _PANN_XLA_FLAGS="${_PANN_XLA_FLAGS} --xla_latency_hiding_scheduler_rerun=1"
-    # The fused-prologue kernels budget ~8 MiB of VMEM scratch per core
-    # (kernels/autotune.vmem_bytes); stop XLA from also claiming an
-    # oversized scratchpad reservation that would shrink what pallas_call
-    # can allocate.
-    _PANN_XLA_FLAGS="${_PANN_XLA_FLAGS} --xla_tpu_scoped_vmem_limit_kib=65536"
-    export XLA_FLAGS="${XLA_FLAGS:-${_PANN_XLA_FLAGS}}"
-    unset _PANN_XLA_FLAGS
-fi
+# always wins. It sets no XLA_FLAGS: the installed libtpu aborts the process
+# at start-up on the --xla_tpu_* and latency-hiding-scheduler flags given
+# there. Nothing needs this file; chip_smoke.py runs without it.
 
 # --- allocator -------------------------------------------------------------
 # Serving engines hold N ladder variants resident; the default 75%
@@ -36,8 +17,6 @@ fi
 export XLA_PYTHON_CLIENT_PREALLOCATE="${XLA_PYTHON_CLIENT_PREALLOCATE:-true}"
 export XLA_PYTHON_CLIENT_MEM_FRACTION="${XLA_PYTHON_CLIENT_MEM_FRACTION:-0.85}"
 
-# --- repro knobs -----------------------------------------------------------
-# Persistent autotune cache (kernels/autotune): per-device-kind block shapes
-# survive restarts. Point at a shared path to reuse tuning across hosts of
-# the same TPU generation.
-export REPRO_AUTOTUNE_CACHE="${REPRO_AUTOTUNE_CACHE:-${HOME}/.cache/repro_pann/autotune.json}"
+# The autotune block cache and JAX's compile cache default to fixed,
+# gitignored paths inside the checkout (.cache/, .jax_cache/); export
+# REPRO_AUTOTUNE_CACHE / JAX_COMPILATION_CACHE_DIR to share them instead.

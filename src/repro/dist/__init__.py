@@ -13,6 +13,3 @@ device or a 512-chip ("pod", "data", "model") mesh:
 
 Module layout and invariants are documented in DESIGN.md §3.
 """
-from repro.dist import compat as _compat
-
-_compat.install()

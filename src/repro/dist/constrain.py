@@ -13,47 +13,19 @@ DESIGN.md §3 for why it must match ``sharding.cache_specs``.
 """
 from __future__ import annotations
 
-import warnings
 from typing import Optional, Union
 
 import jax
+from jax._src import mesh as mesh_lib
 from jax.sharding import Mesh, PartitionSpec as P
 
 Axis = Union[str, tuple, None]
 
 
-def _resolve_thread_resources():
-    try:
-        from jax._src import mesh as mesh_lib
-        return mesh_lib.thread_resources
-    except (ImportError, AttributeError):  # pragma: no cover - old/new jax
-        try:
-            from jax.interpreters import pxla
-            return pxla.thread_resources
-        except (ImportError, AttributeError):
-            return None
-
-
-_THREAD_RESOURCES = _resolve_thread_resources()
-if _THREAD_RESOURCES is None:  # pragma: no cover
-    # distinguish "no mesh active" (normal, silent) from "this jax moved its
-    # mesh-context internals" — the latter silently no-ops EVERY sharding
-    # constraint (16x FLOP bloat class of regressions), so say it loudly once
-    warnings.warn(
-        "repro.dist.constrain: cannot locate jax's mesh-context internals "
-        "in this jax version; all sharding constraints will be no-ops. "
-        "Update _resolve_thread_resources for this jax release.",
-        RuntimeWarning, stacklevel=2)
-
-
 def _context_mesh() -> Optional[Mesh]:
     """The ambient mesh installed by ``with mesh:``, or None outside one."""
-    if _THREAD_RESOURCES is None:  # pragma: no cover
-        return None
-    m = _THREAD_RESOURCES.env.physical_mesh
-    if m is None or m.empty:
-        return None
-    return m
+    m = mesh_lib.thread_resources.env.physical_mesh
+    return None if m.empty else m
 
 
 def _axis_size(mesh: Mesh, name: Axis) -> int:

@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist.compat import shard_map
-
 
 def pipeline_stack(block: Callable, ws: jax.Array, x: jax.Array, *,
                    mesh: Mesh, axis: Hashable, n_micro: int) -> jax.Array:
@@ -62,6 +60,6 @@ def pipeline_stack(block: Callable, ws: jax.Array, x: jax.Array, *,
             jnp.where(stage == last, outs, jnp.zeros_like(outs)), axis)
 
     spec_ws = P(axis)
-    out = shard_map(run_stage, mesh=mesh, in_specs=(spec_ws, P()),
-                    out_specs=P(), check_vma=False)(ws_staged, x_micro)
+    out = jax.shard_map(run_stage, mesh=mesh, in_specs=(spec_ws, P()),
+                        out_specs=P(), check_vma=False)(ws_staged, x_micro)
     return out.reshape(x.shape)

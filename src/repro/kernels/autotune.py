@@ -33,10 +33,13 @@ interpret-mode timings are emulator noise, but recording keeps the cache
 read/write path exercised by CPU CI.
 
 Cache location: ``$REPRO_AUTOTUNE_CACHE`` if set, else
-``~/.cache/repro_pann/autotune.json``. The file is versioned and rewritten
+``.cache/autotune.json`` in the checkout (gitignored), so a run reads no
+file from outside its checkout. The file is versioned and rewritten
 atomically; a corrupt or foreign-version file is ignored, never crashed on.
 Version history: v1 stored bare [bm, bn, bk] triples; v2 adds the schedule
-knobs ({"blocks", "depth", "order"}) and the planes_active key segment.
+knobs ({"blocks", "depth", "order"}) and the planes_active key segment;
+v3 entries hold only whole (32, 128) tiles — a v2 entry may hold a smaller
+block, which the TPU compiler refuses.
 """
 from __future__ import annotations
 
@@ -47,7 +50,9 @@ from typing import Callable, Iterable, NamedTuple, Optional
 
 import jax
 
-CACHE_VERSION = 2
+from repro import CHECKOUT_DIR
+
+CACHE_VERSION = 3
 
 _ENV_VAR = "REPRO_AUTOTUNE_CACHE"
 
@@ -100,8 +105,7 @@ def cache_path() -> str:
     env = os.environ.get(_ENV_VAR)
     if env:
         return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro_pann",
-                        "autotune.json")
+    return os.path.join(CHECKOUT_DIR, ".cache", "autotune.json")
 
 
 def cache_key(m: int, k: int, n: int, planes: int, backend: str,
@@ -156,9 +160,30 @@ def vmem_bytes(bm: int, bn: int, bk: int, k: int, packed: bool,
     return (4 * bm * bk            # fp32 x landing pad
             + bm * k               # persistent int8 codes panel
             + depth * 2 * plane_tile   # DMA slots x 2 signs
-            + bk * bn              # reconstructed-w int8 scratch
+            + 4 * bk * bn          # reconstructed-w int32 scratch
             + 4 * bm * bn          # int32 accumulator
             + 4 * bm * bn)         # f32 output block
+
+
+# Blocks are whole TPU tiles of the int8 operands: 32 rows of the (bm, K)
+# codes panel, 128 lanes along K and N. A smaller M, K or N is padded up to
+# the tile by dispatch (zero rows and columns sliced off the result, zero
+# weight rows an exact no-op), never given a smaller block, which Mosaic
+# refuses.
+ROW_TILE = 32
+LANE_TILE = 128
+
+
+def row_block(m: int, cap: int = 128) -> int:
+    """The row block for ``m`` rows: ``m`` rounded up to ROW_TILE, at most
+    ``cap`` (itself a multiple of ROW_TILE)."""
+    return min(cap, -(-m // ROW_TILE) * ROW_TILE)
+
+
+def lane_block(k: int, cap: int) -> int:
+    """The K or N block for extent ``k``: ``k`` rounded up to LANE_TILE, at
+    most ``cap`` (itself a multiple of LANE_TILE)."""
+    return min(cap, -(-k // LANE_TILE) * LANE_TILE)
 
 
 def heuristic_blocks(m: int, n: int, k: int, planes: int = 7,
@@ -167,18 +192,13 @@ def heuristic_blocks(m: int, n: int, k: int, planes: int = 7,
     """Deterministic default: MXU-aligned blocks shrunk until the act-kernel
     working set fits the VMEM budget (bk first — cheapest to shrink — then
     bm, whose cost is dominated by the bm*K codes panel)."""
-    bm = min(m, 128)
-    bn = min(n, 128)
-    bk = min(k, 512)
-    if packed:
-        bk = max(8, bk - bk % 8)
-    floor_k = 128 if k >= 128 else bk
-    while bk > floor_k and vmem_bytes(bm, bn, bk, k, packed) > vmem_budget:
-        bk = max(floor_k, bk // 2)
-        if packed:
-            bk = max(8, bk - bk % 8)
-    while bm > 8 and vmem_bytes(bm, bn, bk, k, packed) > vmem_budget:
-        bm //= 2
+    bm = row_block(m)
+    bn = lane_block(n, 128)
+    bk = lane_block(k, 512)
+    while bk > LANE_TILE and vmem_bytes(bm, bn, bk, k, packed) > vmem_budget:
+        bk = lane_block(bk // 2, 512)
+    while bm > ROW_TILE and vmem_bytes(bm, bn, bk, k, packed) > vmem_budget:
+        bm = row_block(bm // 2)
     return bm, bn, bk
 
 
@@ -224,11 +244,9 @@ def candidate_blocks(m: int, n: int, k: int, planes: int,
                      ) -> list[tuple[int, int, int]]:
     """The block-shape grid: every MXU-aligned (bm, bn, bk) combination
     that fits the VMEM model, heuristic included."""
-    bms = sorted({min(m, b) for b in (32, 64, 128)})
-    bns = sorted({min(n, b) for b in (128, 256)})
-    bks = sorted({min(k, b) for b in (128, 256, 512)})
-    if packed:
-        bks = sorted({max(8, b - b % 8) for b in bks})
+    bms = sorted({row_block(m, b) for b in (32, 64, 128)})
+    bns = sorted({lane_block(n, b) for b in (128, 256)})
+    bks = sorted({lane_block(k, b) for b in (128, 256, 512)})
     out = {heuristic_blocks(m, n, k, planes, packed, vmem_budget)}
     for bm in bms:
         for bn in bns:

@@ -99,6 +99,16 @@ def parse_backend(spec: str) -> tuple[str, bool]:
     return name, opt == "force"
 
 
+def effective_backend(spec: str) -> str:
+    """What ``spec`` runs on this host — 'ref' for a Pallas backend off TPU
+    unless forced, '<name>:interpret' when forced there — so a summary
+    shows the fallback instead of the name that was asked for."""
+    name, force = parse_backend(spec)
+    if name == "ref" or ops.on_tpu():
+        return name
+    return f"{name}:interpret" if force else "ref"
+
+
 def resolve_backend(spec: str, p: dict) -> tuple[str, bool]:
     """(effective backend, interpret flag) for artifact ``p`` on this host.
 
@@ -305,17 +315,31 @@ def _dispatch_rows(xf: Array, p: dict, s, z, n_lvl, gamma: Array,
     """The backend branch on SEALED scalars: fp32 patch/token rows in,
     (M, N) fp32 out — shared verbatim by ``serving_linear`` and
     ``serving_conv``, which is what makes the conv projection inherit the
-    matmuls' cross-backend bit-exactness rather than re-prove it."""
+    matmuls' cross-backend bit-exactness rather than re-prove it.
+
+    The result leaves through an exit barrier, the twin of the callers'
+    entry barrier: without it, XLA on TPU fuses the jnp oracle's dot into
+    its consumers — e.g. the residual add and the next RMSNorm's sum of
+    squares, as one output fusion — and sums in another order than the
+    loop fusion that reads a Pallas call's result. The programs around
+    the backends then differ by an ulp, and greedy tokens drift apart."""
     w_q = p["w_q"]
     if name == "fused":
         n_planes = (p["w_planes_pos"].shape[-3] if "w_planes_pos" in p
                     else INT8_PLANES)
-        return _matmul_fused(xf, w_q, s, z, n_lvl, gamma, zcol, n_planes,
-                             interpret, shift=shift)
-    if name == "packed":
-        return _matmul_packed(xf, p["w_planes_pos"], p["w_planes_neg"],
-                              s, z, n_lvl, gamma, zcol, interpret,
-                              shift=shift)
+        y = _matmul_fused(xf, w_q, s, z, n_lvl, gamma, zcol, n_planes,
+                          interpret, shift=shift)
+    elif name == "packed":
+        y = _matmul_packed(xf, p["w_planes_pos"], p["w_planes_neg"],
+                           s, z, n_lvl, gamma, zcol, interpret, shift=shift)
+    else:
+        y = _dispatch_ref(xf, w_q, s, z, n_lvl, gamma, zcol, shift)
+    return jax.lax.optimization_barrier(y)
+
+
+def _dispatch_ref(xf: Array, w_q: Array, s, z, n_lvl, gamma: Array,
+                  zcol: Array, shift) -> Array:
+    """The jnp oracle branch of ``_dispatch_rows``."""
     # the jnp oracle materializes the codes (quant.affine_encode — the
     # formula the kernels inline) and seals them so XLA cannot re-fuse
     # the encode into the dot differently than the kernels would
@@ -490,7 +514,8 @@ def decode_attention(q: Array, kv, backend, *, num_kv_heads: int,
     else:
         out = _ref.decode_attention_ref(*args, window=window,
                                         softcap=softcap)
-    return out.reshape(b, h, hd)
+    # exit barrier: the _dispatch_rows contract
+    return jax.lax.optimization_barrier(out).reshape(b, h, hd)
 
 
 # ---------------------------------------------------------------------------

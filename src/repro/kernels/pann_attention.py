@@ -4,15 +4,27 @@ packed bit-plane KV cache (docs/kv_cache.md; DESIGN.md §10).
 The cache stores K/V as unsigned affine codes, bit-plane-decomposed and
 packed 8 bits/byte along head_dim (``kernels.ref.pack_cache_codes`` — NOT
 the weight-plane ``pack_planes``, which packs along K). One grid cell per
-(batch, kv_head); each cell streams its (S, hd/8) plane panels through
+(batch, kv_head); each cell streams its plane panels through
 double-buffered manual DMAs, accumulates the unpacked codes into an int32
-(S, hd) panel, runs the exact int32 QK^T with BOTH zero points corrected
-inside the accumulator (the serving_linear ``zcol`` convention, applied
-twice), the fp32 softmax epilogue in the oracle's exact op sequence, then
-re-quantizes the probabilities to a fixed 2^14 grid for an exact int32 PV
-pass — ``sum_s p = 1`` bounds ``pq @ vq`` by ``127 * 2^14``, int32-safe for
-ANY sequence length. Bit-identical (fp32) to
-``kernels.ref.decode_attention_ref`` (tests/test_kv_cache_quant.py).
+panel, runs the exact int32 QK^T with BOTH zero points corrected inside the
+accumulator (the serving_linear ``zcol`` convention, applied twice), the
+fp32 softmax epilogue in the oracle's exact op sequence (its numerators on
+a fixed 2^15 grid, so the normalizer is an exact int32 sum that no
+reduction order can round differently), then re-quantizes the
+probabilities to a fixed 2^14 grid for an exact int32 PV pass —
+``sum_s p = 1`` bounds ``pq @ vq`` by ``127 * 2^14``, int32-safe for ANY
+sequence length. Bit-identical (fp32) to ``kernels.ref.decode_attention_ref``
+(tests/test_kv_cache_quant.py).
+
+TPU layout: inside the kernel the planes are head-dim-major — (hd/8, S)
+panels, positions on lanes — so a packed panel unpacks exactly like the
+weight kernels' (rows of bytes -> 8 rows of bits each), which is the only
+unpack Mosaic lowers. The wrapper transposes the (B, P, S, K, hd/8) cache
+to (B, P, K, hd/8, S) for this. Mosaic has no int8 vector arithmetic and no
+int32 matmul, so codes are accumulated in int32 and cast to int8 only at
+the MXU operands, and the PV pass splits the <= 2^14 probability codes into
+three int8-range limbs (bits 0-6, 7-13, 14) whose int32 products recombine
+exactly.
 
 Plane skipping: cache codes are <= n_lvl < 2^b, so only the LOW
 ``planes_active`` planes can be nonzero (the opposite prefix from the
@@ -38,7 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import CACHE_PLANES, PROB_SCALE
+from repro.kernels.ref import (CACHE_PLANES, EXP_SCALE, MAX_CACHE_LEN,
+                               PROB_SCALE)
 
 Array = jax.Array
 
@@ -46,31 +59,39 @@ NEG_INF = -1e30     # matches models.attention.NEG_INF / ref._CACHE_NEG_INF
 
 
 def _unpack_plane(pk: Array) -> Array:
-    """(S, d8) uint8 — ONE packed plane — -> (S, hd) int32 {0,1} bits.
-    Byte j, bit i -> element 8j+i: the per-plane slice of the exact inverse
-    of ``ref.pack_cache_codes``."""
-    s, d8 = pk.shape
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 8), 2)
-    bits = (pk[..., None].astype(jnp.int32) >> shifts) & 1   # (S, d8, 8)
-    return bits.reshape(s, d8 * 8)
+    """(d8, S) uint8 — ONE packed plane — -> (hd, S) int32 {0,1} bits.
+    Byte j, bit i -> row 8j+i: the per-plane slice of the exact inverse of
+    ``ref.pack_cache_codes``, transposed."""
+    d8, s = pk.shape
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 8, 1), 1)
+    bits = (pk.astype(jnp.int32)[:, None, :] >> shifts) & 1  # (d8, 8, S)
+    return bits.reshape(d8 * 8, s)
+
+
+def _dot(a: Array, b: Array, contract: tuple[int, int]) -> Array:
+    """Exact int32 product of int8-range int32 operands on the MXU."""
+    return jax.lax.dot_general(
+        a.astype(jnp.int8), b.astype(jnp.int8),
+        (((contract[0],), (contract[1],)), ((), ())),
+        preferred_element_type=jnp.int32)
 
 
 def _decode_attention_kernel(qp_ref, pos_ref, q_ref, kp_hbm, ks_ref, kz_ref,
                              vp_hbm, vs_ref, vz_ref, o_ref, kcode, vcode,
                              kbuf, vbuf, ksem, vsem, *, n_planes: int,
                              hd: int, window, softcap: float,
-                             prob_scale: float):
-    """Grid = (B, K): one cell per (batch, kv_head)."""
+                             exp_scale: float, prob_scale: float):
+    """Grid = (B, K): one cell per (batch, kv_head). Codes are (hd, S)."""
     bi, ki = pl.program_id(0), pl.program_id(1)
     qz = qp_ref[0, 0].astype(jnp.int32)
     q_scale = qp_ref[0, 1]                      # s_q * hd**-0.5, sealed
     k_pact = jnp.round(qp_ref[0, 2]).astype(jnp.int32)
     v_pact = jnp.round(qp_ref[0, 3]).astype(jnp.int32)
     pos = pos_ref[0, 0]
-    s = kcode.shape[0]
+    s = kcode.shape[1]
 
     def plane_dma(buf, hbm, sem, slot, p):
-        return pltpu.make_async_copy(hbm.at[bi, p, :, ki, :],
+        return pltpu.make_async_copy(hbm.at[bi, p, ki],
                                      buf.at[slot], sem.at[slot])
 
     # plane 0 is live for ANY level count >= 1; higher planes are started
@@ -92,23 +113,20 @@ def _decode_attention_kernel(qp_ref, pos_ref, q_ref, kp_hbm, ks_ref, kz_ref,
             plane_dma(kbuf, kp_hbm, ksem, slot, p).wait()
             kcode[...] += jnp.int32(1 << p) * _unpack_plane(kbuf[slot])
 
-    qq = q_ref[...][0, 0]                       # (G, hd) int32 affine codes
-    kq = kcode[...]                             # (S, hd) int32
+    qq = q_ref[...]                             # (G, hd) int32 affine codes
+    kq = kcode[...]                             # (hd, S) int32
 
     # exact int32 QK^T: (qq - z_q) . (kq - z_k) expanded inside the
     # accumulator — codes <= 127 and hd <= 256 keep every term int32-safe
-    dots = jax.lax.dot_general(
-        qq.astype(jnp.int8), kq.astype(jnp.int8), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)       # (G, S)
-    colsum_k = jnp.sum(kq, axis=-1)             # (S,)
-    rowsum_q = jnp.sum(qq, axis=-1)             # (G,)
-    kz = jnp.round(kz_ref[...][0]).astype(jnp.int32)         # (S,)
-    i32 = (dots - qz * colsum_k[None, :] - kz[None, :] * rowsum_q[:, None]
-           + qz * kz[None, :] * hd)
+    dots = _dot(qq, kq, (1, 0))                 # (G, S)
+    colsum_k = jnp.sum(kq, axis=0, keepdims=True)           # (1, S)
+    rowsum_q = jnp.sum(qq, axis=1, keepdims=True)           # (G, 1)
+    kz = jnp.round(kz_ref[...]).astype(jnp.int32)           # (1, S)
+    i32 = dots - qz * colsum_k - kz * rowsum_q + qz * kz * hd
 
     # fp32 epilogue — the oracle's exact op sequence (ref.py): change both
     # or neither, the parity suite holds them bit-identical
-    sc = (i32.astype(jnp.float32) * q_scale) * ks_ref[...][0][None, :]
+    sc = (i32.astype(jnp.float32) * q_scale) * ks_ref[...]
     if softcap > 0:
         sc = softcap * jnp.tanh(sc / softcap)
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
@@ -117,8 +135,9 @@ def _decode_attention_kernel(qp_ref, pos_ref, q_ref, kp_hbm, ks_ref, kz_ref,
         valid &= (pos - k_pos) < window
     sc = jnp.where(valid, sc, NEG_INF)
     m = jnp.max(sc, axis=-1, keepdims=True)
-    p_ = jnp.exp(sc - m)
-    p_ = p_ / jnp.sum(p_, axis=-1, keepdims=True)
+    eq = jnp.round(jnp.exp(sc - m) * exp_scale).astype(jnp.int32)
+    p_ = eq.astype(jnp.float32) / jnp.sum(
+        eq, axis=-1, keepdims=True).astype(jnp.float32)
 
     # stream + accumulate the V planes (their DMAs overlapped the QK^T work)
     vcode[...] = jnp.zeros_like(vcode)
@@ -134,18 +153,18 @@ def _decode_attention_kernel(qp_ref, pos_ref, q_ref, kp_hbm, ks_ref, kz_ref,
 
     # exact int32 PV: rescale every position into the largest valid V scale,
     # re-quantize the probabilities, subtract the V zero point in-accumulator
-    vq = vcode[...]                                          # (S, hd) int32
-    vs = vs_ref[...][0]                                      # (S,)
-    sv_ref = jnp.maximum(jnp.max(jnp.where(valid[0], vs, 0.0)), 1e-12)
+    vq = vcode[...]                                          # (hd, S) int32
+    vs = vs_ref[...]                                         # (1, S)
+    sv_ref = jnp.maximum(jnp.max(jnp.where(valid, vs, 0.0)), 1e-12)
     ratio = vs / sv_ref
-    pq = jnp.round(p_ * ratio[None, :] * prob_scale).astype(jnp.int32)
-    pv = jax.lax.dot_general(pq, vq, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.int32)  # (G, hd)
-    vz = jnp.round(vz_ref[...][0]).astype(jnp.int32)
-    corr = jnp.sum(pq * vz[None, :], axis=-1)                # (G,)
+    pq = jnp.round(p_ * ratio * prob_scale).astype(jnp.int32)   # (G, S)
+    pv = (_dot(pq & 127, vq, (1, 1))
+          + 128 * _dot((pq >> 7) & 127, vq, (1, 1))
+          + 16384 * _dot(pq >> 14, vq, (1, 1)))              # (G, hd)
+    vz = jnp.round(vz_ref[...]).astype(jnp.int32)            # (1, S)
+    corr = jnp.sum(pq * vz, axis=-1, keepdims=True)          # (G, 1)
     scale = sv_ref / prob_scale
-    out = (pv - corr[:, None]).astype(jnp.float32) * scale
-    o_ref[...] = out.reshape(o_ref.shape)
+    o_ref[...] = (pv - corr).astype(jnp.float32) * scale
 
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap",
@@ -169,6 +188,7 @@ def decode_attention(qq: Array, q_z: Array, q_scale: Array,
     assert kh == kh2 and d8 * 8 == hd, (qq.shape, k_planes.shape)
     assert v_planes.shape == k_planes.shape
     assert n_planes <= CACHE_PLANES, n_planes
+    assert s <= MAX_CACHE_LEN, s
     if k_pact is None:
         k_pact = jnp.float32(n_planes)
     if v_pact is None:
@@ -183,30 +203,41 @@ def decode_attention(qq: Array, q_z: Array, q_scale: Array,
 
     kernel = functools.partial(_decode_attention_kernel, n_planes=n_planes,
                                hd=hd, window=window, softcap=softcap,
-                               prob_scale=PROB_SCALE)
-    row_spec = pl.BlockSpec((1, s), lambda bi, ki: (bi, 0))
+                               exp_scale=EXP_SCALE, prob_scale=PROB_SCALE)
+    # The (B, S) scale/zero rows ride as (B, 1, S): a block's last two dims
+    # must be tile multiples or whole, and (1, S) is whole on that view.
+    # Positions ride on lanes, so S is padded to whole lane tiles; padded
+    # positions lie past ``pos`` and are masked like unwritten ones.
+    pad = (-s) % 128
+    s += pad
+    row_spec = pl.BlockSpec((None, 1, s), lambda bi, ki: (bi, 0, 0))
+    rows = lambda a: jnp.pad(a, ((0, 0), (0, pad))).reshape(b, 1, s)  # noqa: E731
+    head_major = lambda a: jnp.pad(                             # noqa: E731
+        jnp.transpose(a, (0, 1, 3, 4, 2)), ((0, 0),) * 4 + ((0, pad),))
+    qo_spec = pl.BlockSpec((None, None, g, hd),
+                           lambda bi, ki: (bi, ki, 0, 0))
     return pl.pallas_call(
         kernel,
         grid=(b, kh),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # [q_z, q_scale, pacts]
             pl.BlockSpec(memory_space=pltpu.SMEM),   # pos
-            pl.BlockSpec((1, 1, g, hd), lambda bi, ki: (bi, ki, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),    # K planes (manual DMA)
+            qo_spec,
+            pl.BlockSpec(memory_space=pl.ANY),    # K planes (manual DMA)
             row_spec, row_spec,                      # K s/z
-            pl.BlockSpec(memory_space=pltpu.ANY),    # V planes (manual DMA)
+            pl.BlockSpec(memory_space=pl.ANY),    # V planes (manual DMA)
             row_spec, row_spec,                      # V s/z
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda bi, ki: (bi, ki, 0, 0)),
+        out_specs=qo_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, hd), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((s, hd), jnp.int32),          # accumulated K codes
-            pltpu.VMEM((s, hd), jnp.int32),          # accumulated V codes
-            pltpu.VMEM((2, s, d8), jnp.uint8),       # K plane slots
-            pltpu.VMEM((2, s, d8), jnp.uint8),       # V plane slots
+            pltpu.VMEM((hd, s), jnp.int32),          # accumulated K codes
+            pltpu.VMEM((hd, s), jnp.int32),          # accumulated V codes
+            pltpu.VMEM((2, d8, s), jnp.uint8),       # K plane slots
+            pltpu.VMEM((2, d8, s), jnp.uint8),       # V plane slots
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(qp, pos2, qq.astype(jnp.int32), k_planes, k_s, k_z,
-      v_planes, v_s, v_z)
+    )(qp, pos2, qq.astype(jnp.int32), head_major(k_planes), rows(k_s),
+      rows(k_z), head_major(v_planes), rows(v_s), rows(v_z))

@@ -54,11 +54,14 @@ def _pann_matmul_kernel(x_ref, pos_ref, neg_ref, sx_ref, gamma_ref, zcol_ref,
     x = x_ref[...]                      # (bm, bk) int8, non-negative codes
 
     if mode == "fused":
-        w = jnp.zeros(pos_ref.shape[1:], jnp.int8)
+        # int32 shift-add (Mosaic has no int8 vector arithmetic); the
+        # reconstructed codes fit int8 and are cast at the MXU operand
+        w = jnp.zeros(pos_ref.shape[1:], jnp.int32)
         for p in range(n_planes):
-            w = w + (jnp.int8(1 << p)) * (pos_ref[p] - neg_ref[p])
+            w = w + (1 << p) * (pos_ref[p].astype(jnp.int32)
+                                - neg_ref[p].astype(jnp.int32))
         acc_ref[...] += jax.lax.dot_general(
-            x, w, (((1,), (0,)), ((), ())),
+            x, w.astype(jnp.int8), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
     else:  # 'planes': per-plane addition-only passes, pos/neg separated
         acc_p = jnp.zeros(acc_ref.shape, jnp.int32)
@@ -217,7 +220,8 @@ def _pann_matmul_act_kernel(qp_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref,
         # w lives in a VMEM scratch (not a loop-carried register) because
         # the per-plane bodies must be pl.when-predicated — a wait on a
         # never-started copy would hang — and predicated bodies can only
-        # mutate refs
+        # mutate refs. It is int32: Mosaic has no int8 vector arithmetic,
+        # so the shift-add runs wide and casts at the MXU operand.
         w_ref[...] = jnp.zeros_like(w_ref)
         for p in range(n_planes):
             @pl.when(p >= shift)
@@ -230,10 +234,11 @@ def _pann_matmul_act_kernel(qp_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref,
                               nxt % depth, nxt).start()
                 plane_dma(pos_buf, pos_hbm, pos_sem, slot, p).wait()
                 plane_dma(neg_buf, neg_hbm, neg_sem, slot, p).wait()
-                w_ref[...] += jnp.int8(1 << p) * (pos_buf[slot]
-                                                  - neg_buf[slot])
+                w_ref[...] += (1 << p) * (
+                    pos_buf[slot].astype(jnp.int32)
+                    - neg_buf[slot].astype(jnp.int32))
         acc_ref[...] += jax.lax.dot_general(
-            x, w_ref[...], (((1,), (0,)), ((), ())),
+            x, w_ref[...].astype(jnp.int8), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
     else:  # 'planes': per-plane addition-only passes, pos/neg separated
         for p in range(n_planes):
@@ -331,9 +336,9 @@ def pann_matmul_act(x: Array, planes_pos: Array, planes_neg: Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),       # qparams
-            pl.BlockSpec(memory_space=pltpu.ANY),        # x (manual DMA)
-            pl.BlockSpec(memory_space=pltpu.ANY),        # planes_pos
-            pl.BlockSpec(memory_space=pltpu.ANY),        # planes_neg
+            pl.BlockSpec(memory_space=pl.ANY),        # x (manual DMA)
+            pl.BlockSpec(memory_space=pl.ANY),        # planes_pos
+            pl.BlockSpec(memory_space=pl.ANY),        # planes_neg
             pl.BlockSpec((1, bn), nidx),
             pl.BlockSpec((1, bn), nidx),
         ],
@@ -344,7 +349,7 @@ def pann_matmul_act(x: Array, planes_pos: Array, planes_neg: Array,
             pltpu.VMEM((bm, k), jnp.int8),               # persistent codes
             pltpu.VMEM((depth, bk, bn), jnp.int8),       # plane slots (pos)
             pltpu.VMEM((depth, bk, bn), jnp.int8),       # plane slots (neg)
-            pltpu.VMEM((bk, bn), jnp.int8),              # reconstructed w
+            pltpu.VMEM((bk, bn), jnp.int32),             # reconstructed w
             pltpu.VMEM((bm, bn), jnp.int32),             # accumulator
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA((depth,)),

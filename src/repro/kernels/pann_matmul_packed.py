@@ -48,6 +48,17 @@ def unpack_planes(packed: Array, k: int) -> Array:
     return bits.reshape(*lead, k8 * 8, n)[..., :k, :].astype(jnp.int8)
 
 
+def _unpack_tile(tile: Array) -> Array:
+    """(bk//8, bn) uint8 packed tile -> (bk, bn) int32 {0,1} bits.
+
+    Mosaic has no int8/uint8 vector arithmetic, so the shifts run in int32
+    and the result is cast to int8 only at the MXU operand."""
+    k8, bn = tile.shape
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 8, 1), 1)
+    bits = (tile.astype(jnp.int32)[:, None, :] >> shifts) & 1
+    return bits.reshape(k8 * 8, bn)
+
+
 def _kernel(x_ref, pos_ref, neg_ref, sx_ref, gamma_ref, zcol_ref, o_ref,
             acc_ref, *, n_planes: int, k_steps: int):
     kk = pl.program_id(2)
@@ -58,18 +69,13 @@ def _kernel(x_ref, pos_ref, neg_ref, sx_ref, gamma_ref, zcol_ref, o_ref,
 
     x = x_ref[...]                                  # (bm, bk) int8
     bk = x.shape[1]
-    shifts = jnp.arange(8, dtype=jnp.uint8)
 
-    def unpack(ref, p):
-        pk = ref[p]                                 # (bk//8, bn) uint8
-        bits = (pk[:, None, :] >> shifts[None, :, None]) & jnp.uint8(1)
-        return bits.reshape(bk, -1).astype(jnp.int8)
-
-    w = jnp.zeros((bk, o_ref.shape[1]), jnp.int8)
+    w = jnp.zeros((bk, o_ref.shape[1]), jnp.int32)
     for p in range(n_planes):
-        w = w + jnp.int8(1 << p) * (unpack(pos_ref, p) - unpack(neg_ref, p))
+        w = w + (1 << p) * (_unpack_tile(pos_ref[p]) - _unpack_tile(neg_ref[p]))
     acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        x, w.astype(jnp.int8), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(kk == k_steps - 1)
     def _done():
@@ -141,7 +147,6 @@ def _act_kernel(qp_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref, zcol_ref, o_ref,
         shift = jnp.int32(0)
     bm = xbuf.shape[0]
     bn = o_ref.shape[1]
-    shifts = jnp.arange(8, dtype=jnp.uint8)
 
     def _encode_panel():
         cp = pltpu.make_async_copy(
@@ -168,10 +173,6 @@ def _act_kernel(qp_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref, zcol_ref, o_ref,
             hbm.at[p, pl.ds(kk * (bk // 8), bk // 8), pl.ds(j * bn, bn)],
             buf.at[slot], sem.at[slot])
 
-    def unpack(tile):                           # (bk//8, bn) -> (bk, bn)
-        bits = (tile[:, None, :] >> shifts[None, :, None]) & jnp.uint8(1)
-        return bits.reshape(bk, bn).astype(jnp.int8)
-
     # predicated pipeline fill from the first LIVE plane (see pann_matmul)
     for p0 in range(n_planes):
         @pl.when(shift == p0)
@@ -195,10 +196,10 @@ def _act_kernel(qp_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref, zcol_ref, o_ref,
                           nxt).start()
             plane_dma(pos_buf, pos_hbm, pos_sem, slot, p).wait()
             plane_dma(neg_buf, neg_hbm, neg_sem, slot, p).wait()
-            w_ref[...] += jnp.int8(1 << p) * (unpack(pos_buf[slot])
-                                              - unpack(neg_buf[slot]))
+            w_ref[...] += (1 << p) * (_unpack_tile(pos_buf[slot])
+                                      - _unpack_tile(neg_buf[slot]))
     acc_ref[...] += jax.lax.dot_general(
-        x, w_ref[...], (((1,), (0,)), ((), ())),
+        x, w_ref[...].astype(jnp.int8), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
 
     @pl.when(kk == k_steps - 1)
@@ -257,9 +258,9 @@ def pann_matmul_packed_act(x: Array, packed_pos: Array, packed_neg: Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),       # qparams
-            pl.BlockSpec(memory_space=pltpu.ANY),        # x (manual DMA)
-            pl.BlockSpec(memory_space=pltpu.ANY),        # packed_pos
-            pl.BlockSpec(memory_space=pltpu.ANY),        # packed_neg
+            pl.BlockSpec(memory_space=pl.ANY),        # x (manual DMA)
+            pl.BlockSpec(memory_space=pl.ANY),        # packed_pos
+            pl.BlockSpec(memory_space=pl.ANY),        # packed_neg
             pl.BlockSpec((1, bn), nidx),
             pl.BlockSpec((1, bn), nidx),
         ],
@@ -270,7 +271,7 @@ def pann_matmul_packed_act(x: Array, packed_pos: Array, packed_neg: Array,
             pltpu.VMEM((bm, k), jnp.int8),               # persistent codes
             pltpu.VMEM((depth, bk // 8, bn), jnp.uint8),  # plane slots (pos)
             pltpu.VMEM((depth, bk // 8, bn), jnp.uint8),  # plane slots (neg)
-            pltpu.VMEM((bk, bn), jnp.int8),              # reconstructed w
+            pltpu.VMEM((bk, bn), jnp.int32),             # reconstructed w
             pltpu.VMEM((bm, bn), jnp.int32),             # accumulator
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA((depth,)),

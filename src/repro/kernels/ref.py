@@ -53,6 +53,14 @@ CACHE_PLANES = 7
 # pq @ vq <= 127 * 2^14 — int32-safe for ANY sequence length.
 PROB_SCALE = float(1 << 14)
 
+# The softmax numerators exp(sc - max) in (0, 1] are put on this fixed grid
+# before they are summed, so the normalizer is an exact int32 sum: a float
+# sum's rounding depends on its reduction order, which XLA and Mosaic choose
+# differently (and Mosaic sums the lane-padded row). int32-safe while the
+# cache holds at most MAX_CACHE_LEN positions.
+EXP_SCALE = float(1 << 15)
+MAX_CACHE_LEN = (1 << 31) // (1 << 15) - 1
+
 _CACHE_NEG_INF = -1e30   # matches models.attention.NEG_INF
 
 
@@ -136,8 +144,9 @@ def decode_attention_ref(qq: Array, q_z: Array, q_scale: Array,
         valid &= (pos_b[:, None] - k_pos[None, :]) < window
     sc = jnp.where(valid[:, None, None, :], sc, _CACHE_NEG_INF)
     m = jnp.max(sc, axis=-1, keepdims=True)
-    p = jnp.exp(sc - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    eq = jnp.round(jnp.exp(sc - m) * EXP_SCALE).astype(jnp.int32)
+    p = eq.astype(jnp.float32) / jnp.sum(
+        eq, axis=-1, keepdims=True).astype(jnp.float32)
     # exact int32 PV: probs are rescaled into V's largest per-batch scale,
     # re-quantized at prob_scale, and the V zero point is subtracted inside
     # the accumulator (same zcol convention as serving_linear)

@@ -37,6 +37,7 @@ from repro import configs
 from repro.configs.base import QuantConfig
 from repro.core import costs, planner
 from repro.data.pipeline import frontend_raw_stub, frontend_stub
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as MD
 from repro.models import serving
 from repro.serve_engine import (EncodeEngine, EncodeRequest, Request,
@@ -195,7 +196,9 @@ def serve_ladder(args) -> dict:
                          cache_bits=cache_bits,
                          artifact_format=args.artifact_format,
                          frontend_kwargs_fn=fe_fn)
+    t0 = time.monotonic()
     engine.warmup()
+    warmup_s = time.monotonic() - t0
     total_macs = sum(m.macs for m in engine.profile)
     for op in engine.ladder:
         if op.lw is not None:
@@ -222,11 +225,15 @@ def serve_ladder(args) -> dict:
     summary = {
         "arch": cfg.name,
         "mode": "ladder",
+        "num_layers": cfg.num_layers,
+        "d_model": cfg.d_model,
         "engine": engine.describe(),
+        "pallas_calls_in_step": engine.pallas_calls_in_step(),
         "requests": [{"uid": r.uid, "rung_bits": r.rung_bits,
-                      "sample": r.tokens[:8], **r.metadata}
+                      "token_ids": r.tokens, **r.metadata}
                      for r in responses],
         "generated": n_tok,
+        "warmup_s": round(warmup_s, 3),
         "wall_s": round(dt, 3),
         "tok_per_s": round(n_tok / max(dt, 1e-9), 1),
     }
@@ -314,6 +321,7 @@ def main(argv=None) -> dict:
                          "here (default: a fresh temp dir)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.artifact_format == "legacy":
         raise SystemExit(

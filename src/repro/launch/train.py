@@ -42,7 +42,9 @@ from repro.data.pipeline import SyntheticLM, frontend_stub
 from repro.dist import sharding as SH
 from repro.dist.fault import StepMonitor
 from repro.launch import steps as ST
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
+from repro.optim.optimizers import AdamWState
 
 # held-out eval stream: same generator family as training, disjoint seed
 EVAL_SEED_OFFSET = 1
@@ -125,6 +127,22 @@ def make_eval_batch(cfg, args) -> dict:
     return batch
 
 
+def init_sharded_state(key, cfg, tcfg, mesh, par, *, calibrate: bool):
+    """(state, shardings): the TrainState initialized straight into its
+    shardings on ``mesh``. Built whole on one device first, a full-width
+    model's fp32 params + AdamW moments would not fit that device before
+    they were spread."""
+    init = partial(ST.make_train_state, cfg=cfg, tcfg=tcfg,
+                   calibrate=calibrate)
+    shapes = jax.eval_shape(init, key)
+    pspecs = SH.param_specs(shapes.params, mesh, par)
+    state_sh = SH.to_named(ST.TrainState(
+        params=pspecs, opt=AdamWState(mu=pspecs, nu=pspecs, count=P()),
+        step=P(), calib=jax.tree_util.tree_map(lambda _: P(), shapes.calib)),
+        mesh)
+    return jax.jit(init, out_shardings=state_sh)(key), state_sh
+
+
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
@@ -170,6 +188,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--ckpt_every", type=int, default=50)
     ap.add_argument("--log_every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     try:
         cfg, tcfg, par = build(args)
@@ -186,7 +205,6 @@ def main(argv=None) -> dict:
     data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=args.seq,
                        global_batch=args.batch, seed=args.seed)
 
-    pspec_fn = lambda tree: SH.param_specs(tree, mesh, par)
     key = jax.random.PRNGKey(args.seed)
 
     def cfg_for_step(step):
@@ -201,17 +219,8 @@ def main(argv=None) -> dict:
     meta_args = {k: getattr(args, k) for k in TRAIN_ARG_KEYS}
 
     with mesh:
-        state = ST.make_train_state(key, cfg, tcfg, calibrate=qat)
-        pspecs = pspec_fn(jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state.params))
-        from repro.optim.optimizers import AdamWState
-        calib_specs = jax.tree_util.tree_map(lambda _: P(), state.calib)
-        state_specs = ST.TrainState(
-            params=pspecs, opt=AdamWState(mu=pspecs, nu=pspecs, count=P()),
-            step=P(), calib=calib_specs)
-        state_sh = SH.to_named(state_specs, mesh)
-        state = jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, s), state, state_sh)
+        state, state_sh = init_sharded_state(key, cfg, tcfg, mesh, par,
+                                             calibrate=qat)
 
         monitor = StepMonitor()
         start_step = 0

@@ -16,6 +16,7 @@ rungs share one compilation) — the full (b~x, R) operating point.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Mapping, Optional
 
 import jax
@@ -102,6 +103,37 @@ def _planes_artifact(codes, plane_count: int) -> dict:
         planes = pann_core.bitplane_decompose(half, plane_count)
         out[key] = pack_planes(jnp.moveaxis(planes, 0, -3))
     return out
+
+
+@functools.partial(jax.jit, static_argnames=("store_dtype", "pack"))
+def _store_leaf(w, r_max, *, store_dtype, pack: bool) -> dict:
+    """One projection's store leaves at budget ``r_max``: clipped int codes,
+    gamma, and (``pack``) the plane stacks. Quantization is per output
+    channel over the fan-in axis, so each (K, N) matrix of a scan-stacked
+    leaf is independent: ``lax.map`` quantizes them one at a time, which
+    bounds the build's temporaries (int32 planes are 28 B/weight) by one
+    matrix instead of the whole stack — what lets a full-width model's
+    store build next to its fp32 params on one chip."""
+    def one(m):
+        w_q, gamma = pann_core.pann_quantize(m, r_max, axis=0)
+        codes = jnp.clip(w_q, -127, 127)
+        out = {"w_q": codes.astype(store_dtype),
+               "w_scale": gamma.astype(jnp.float32)}
+        if pack:
+            out.update(_planes_artifact(codes, LADDER_PLANE_COUNT))
+        return out
+
+    lead = w.shape[:-2]
+    out = jax.lax.map(one, w.astype(jnp.float32).reshape((-1,) + w.shape[-2:]))
+    return jax.tree_util.tree_map(lambda a: a.reshape(lead + a.shape[1:]),
+                                  out)
+
+
+@jax.jit
+def _view_colsum(codes, shift):
+    """Per-output-channel sum of the codes a ``shift``-plane view realizes
+    (fused, so no int32 copy of the code stack is materialized)."""
+    return jnp.sum(pann_core.masked_codes(codes, shift), axis=-2)
 
 
 def _cache_artifact(stack, cache_role_bits, calib) -> dict:
@@ -508,16 +540,10 @@ def build_weight_store(params: Any, cfg: ModelConfig,
                 points = {k: _resolve_point(r_by_rung[k], trail)
                           for k in keys}
                 r_max = max(r for r, _ in points.values())
-                w_q, gamma = pann_core.pann_quantize(
-                    w.astype(jnp.float32), r_max, axis=w.ndim - 2)
-                codes = jnp.clip(w_q, -127, 127)
-                shared = {
-                    "w_q": codes.astype(store_dtype),
-                    "w_scale": gamma.astype(jnp.float32),
-                }
-                if pack_planes:
-                    shared.update(
-                        _planes_artifact(codes, LADDER_PLANE_COUNT))
+                shared = _store_leaf(w, jnp.float32(r_max),
+                                     store_dtype=store_dtype,
+                                     pack=pack_planes)
+                codes = shared["w_q"]
                 if "b" in node:
                     shared["b"] = node["b"]
                 stack = w.shape[:-2]
@@ -526,13 +552,12 @@ def build_weight_store(params: Any, cfg: ModelConfig,
                     r_mod, ab = points[k]
                     sh = pann_core.view_shift(r_max, r_mod,
                                               LADDER_PLANE_COUNT - 1)
-                    masked = pann_core.masked_codes(codes, sh)
                     v = dict(shared)
                     v["plane_shift"] = jnp.full(stack, float(sh),
                                                 jnp.float32)
                     # the view's zero-point row: colsum of the codes the
                     # plane-skipping kernels REALIZE, not the stored ones
-                    v["w_colsum"] = jnp.sum(masked, axis=-2)
+                    v["w_colsum"] = _view_colsum(codes, jnp.int32(sh))
                     if ab is not None:
                         v.update(_act_leaves(stack, ab, trail, calib))
                     views[k] = v

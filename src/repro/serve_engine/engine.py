@@ -274,6 +274,17 @@ class ServeEngine:
             jax.block_until_ready(
                 self._step(self.variants[op.bits], state, tok)[0])
         self.compilations_after_warmup = self._jit_cache_size()
+        self._warm_args = (self.variants[self.ladder[0].bits], state, tok)
+
+    def pallas_calls_in_step(self) -> int:
+        """Pallas TPU kernels (``tpu_custom_call``) in the compiled decode
+        step — 0 when every projection ran through XLA, which a summary
+        must show rather than hide. Lowering the warmed-up arguments again
+        finds the executable warmup built; nothing recompiles."""
+        if self.compilations_after_warmup is None:
+            raise RuntimeError("call warmup() first")
+        text = self._step.lower(*self._warm_args).compile().as_text()
+        return text.count("tpu_custom_call")
 
     def assert_no_recompile(self) -> None:
         """After serving: the jit cache must not have grown past warmup."""
@@ -540,6 +551,8 @@ class ServeEngine:
             "allocation": self.allocation,
             "artifact_format": self.artifact_format,
             "backend": self.backend or "legacy",
+            "effective_backend": (dispatch.effective_backend(self.backend)
+                                  if self.backend else "legacy"),
             "cache_bits": self.cache_bits,
             "cache_bits_by_rung": dict(self._cache_bits_by_rung) or None,
             "ladder": [{"bits": op.bits, "b_x_tilde": op.b_x_tilde,

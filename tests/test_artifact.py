@@ -144,7 +144,7 @@ def test_full_decode_step_view_vs_materialized(setup, ws, backend):
 # Truncation consistency (property-based)
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(st.integers(0, 6), st.integers(0, 10_000))
 def test_rung_codes_are_top_planes_of_max_codes(shift, seed):
     """The scheme's defining identity: the integer weights a shift-s view
@@ -161,7 +161,7 @@ def test_rung_codes_are_top_planes_of_max_codes(shift, seed):
         np.asarray(pann_core.masked_codes(codes, shift)), np.asarray(top))
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(st.floats(0.2, 120.0), st.floats(0.2, 120.0))
 def test_view_shift_snaps_within_sqrt2(r_max, r):
     r = min(r, r_max)                   # rungs never exceed the store

@@ -26,11 +26,12 @@ def run_py(body: str) -> dict:
 def test_compressed_allreduce_error_feedback():
     r = run_py("""
         import jax, jax.numpy as jnp, json
+        from repro.launch.mesh import make_mesh
         import numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.dist.collectives import compressed_psum_mean
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         g_global = jnp.asarray(np.random.default_rng(0)
                                .standard_normal((8, 64)), jnp.float32)
 
@@ -68,10 +69,11 @@ def test_compressed_allreduce_error_feedback():
 def test_gpipe_matches_sequential():
     r = run_py("""
         import jax, jax.numpy as jnp, json, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.dist.pipeline import pipeline_stack
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         rng = np.random.default_rng(0)
         n_groups, d = 8, 16
         ws = jnp.asarray(rng.standard_normal((n_groups, d, d)) * 0.2,
@@ -103,9 +105,10 @@ def test_gpipe_matches_sequential():
 def test_gpipe_is_differentiable():
     r = run_py("""
         import jax, jax.numpy as jnp, json, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.dist.pipeline import pipeline_stack
 
-        mesh = jax.make_mesh((2,), ("pod",))
+        mesh = make_mesh((2,), ("pod",))
         rng = np.random.default_rng(1)
         ws = jnp.asarray(rng.standard_normal((4, 8, 8)) * 0.3, jnp.float32)
         x = jnp.asarray(rng.standard_normal((4, 2, 8)), jnp.float32)
@@ -141,6 +144,7 @@ def test_sharded_train_step_matches_single_device():
     trajectory as single-device execution (same seed, same data)."""
     body_tpl = """
         import jax, jax.numpy as jnp, json
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from functools import partial
         from repro import configs
@@ -156,7 +160,7 @@ def test_sharded_train_step_matches_single_device():
         par = ParallelConfig(remat="none")
         data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=16,
                            global_batch=8, seed=0)
-        mesh = jax.make_mesh(MESH, ("data", "model"))
+        mesh = make_mesh(MESH, ("data", "model"))
         with mesh:
             state = ST.make_train_state(jax.random.PRNGKey(0), cfg, tcfg)
             pspecs = SH.param_specs(jax.tree_util.tree_map(
@@ -190,19 +194,20 @@ def test_elastic_remesh_restore():
     rescaling across checkpoint boundaries."""
     r = run_py("""
         import jax, jax.numpy as jnp, json, tempfile, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt import checkpoint as ck
 
         d = tempfile.mkdtemp()
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         sh_a = {"w": NamedSharding(mesh_a, P("data", "model"))}
         tree_a = jax.tree_util.tree_map(jax.device_put, tree, sh_a)
         ck.save(d, 1, tree_a)
 
         results = []
         for shape in [(2, 4), (8, 1)]:
-            mesh_b = jax.make_mesh(shape, ("data", "model"))
+            mesh_b = make_mesh(shape, ("data", "model"))
             sh_b = {"w": NamedSharding(mesh_b, P("data", "model"))}
             out = ck.restore(d, 1, tree, sh_b)
             results.append(bool((np.asarray(out["w"]) ==

@@ -151,6 +151,10 @@ def test_fallback_off_tpu_is_ref():
     leaf = _leaf(64, 32)
     assert dispatch.resolve_backend("fused", leaf) == ("ref", False)
     assert dispatch.resolve_backend("fused:force", leaf) == ("fused", True)
+    # the fallback is reported, not hidden behind the requested name
+    assert dispatch.effective_backend("packed") == "ref"
+    assert dispatch.effective_backend("packed:force") == "packed:interpret"
+    assert dispatch.effective_backend("ref") == "ref"
     x = jnp.asarray(RNG.standard_normal((4, 64)), jnp.float32)
     np.testing.assert_array_equal(
         np.asarray(dispatch.serving_linear(x, leaf, "fused")),
@@ -222,6 +226,11 @@ def test_ladder_bitwise_across_backends_no_recompile(allocation):
         toks = [r.tokens for r in eng.generate(reqs)]
         eng.assert_no_recompile()
         assert eng.describe()["backend"] == spec
+        assert eng.describe()["effective_backend"] == \
+            dispatch.effective_backend(spec)
+        # interpret mode lowers Pallas to plain HLO: no TPU custom call
+        assert eng.pallas_calls_in_step() == 0
+        eng.assert_no_recompile()
         if spec != "ref":
             ref_toks = [r.tokens for r in engines["ref"].generate(reqs)]
             assert toks == ref_toks, spec

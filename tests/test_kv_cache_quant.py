@@ -112,6 +112,24 @@ def test_ref_ragged_positions_match_per_batch_kernel_calls():
                                       np.asarray(want[i:i + 1]))
 
 
+def test_ref_is_invariant_to_the_order_of_positions():
+    """The softmax normalizer is an exact int32 sum, so no reduction order
+    (XLA's over S, Mosaic's over the lane-padded row) can round it
+    differently: permuting a full cache's positions leaves the output
+    bit-identical."""
+    b, s, kh, g, hd = 2, 48, 4, 2, 16
+    qq, q_z, q_scale, kp, ks, kz, vp, vs, vz = _mk_inputs(4, b, s, kh, g,
+                                                           hd, 5)
+    perm = np.random.default_rng(4).permutation(s)
+    pos = jnp.int32(s - 1)
+    want = ref.decode_attention_ref(qq, q_z, q_scale, kp, ks, kz, vp, vs,
+                                    vz, pos)
+    got = ref.decode_attention_ref(qq, q_z, q_scale, kp[:, :, perm],
+                                   ks[:, perm], kz[:, perm], vp[:, :, perm],
+                                   vs[:, perm], vz[:, perm], pos)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_rung_switch_zero_high_planes_parity():
     """A rung switch changes only the CODE WIDTH: a 3-bit rung's codes in
     the pinned 7-plane layout leave the high planes zero. Parity must hold
@@ -173,7 +191,7 @@ def test_incremental_writes_match_batch_pack():
 # property-based codec round trips (vendored hypothesis stub)
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(st.integers(1, 7), st.integers(1, 4), st.integers(1, 6),
        st.integers(0, 10_000))
 def test_codec_round_trip(bits, lead, d8, seed):
@@ -187,7 +205,7 @@ def test_codec_round_trip(bits, lead, d8, seed):
     np.testing.assert_array_equal(np.asarray(back), codes)
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(st.integers(2, 7), st.floats(0.1, 8.0), st.integers(0, 10_000))
 def test_affine_cache_round_trip_error_bound(bits, spread, seed):
     """Encoding a tensor through the cache codec (affine encode -> pack ->

@@ -69,6 +69,7 @@ def test_capacity_dispatch_matches_scan_multidev():
     code = textwrap.dedent("""
         import dataclasses, json
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import configs
         from repro.configs.base import MoEConfig
         from repro.models import mlp as M
@@ -81,7 +82,7 @@ def test_capacity_dispatch_matches_scan_multidev():
         x = jnp.asarray(np.random.default_rng(0).standard_normal(
             (8, 16, cfg.d_model)), jnp.float32)
         y_scan, aux_scan = M.apply_moe(x, p, cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         with mesh:
             y_cap, aux_cap = jax.jit(
                 lambda x_, p_: apply_moe_capacity(x_, p_, cfg, mesh))(x, p)

@@ -91,7 +91,7 @@ _PROFILES = {arch: costs.module_cost_profile(configs.get_config(arch))
                           "zamba2-1.2b", "seamless-m4t-medium")}
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(st.floats(min_value=pw.p_mac_unsigned(2),
                  max_value=pw.p_mac_unsigned(8)),
        st.sampled_from(sorted(_PROFILES)))

@@ -32,7 +32,7 @@ def test_gate_thresholds_are_sane_floors():
 def test_analyze_record_synthetic_math():
     """The hand-checkable record from assert_invariants, verified term by
     term against the peaks table rather than just for finiteness."""
-    pk = device_peaks("TPU v5e")
+    pk = device_peaks("TPU v5 lite")
     rec = {
         "arch": "synthetic", "shape": "s", "mesh": "single", "n_devices": 4,
         "flops_per_device_corrected": 1e12,

@@ -348,9 +348,9 @@ def test_variant_cache_mesh_sharded():
         import dataclasses, json
         import jax, jax.numpy as jnp
         import numpy as np
-        from jax.sharding import Mesh
         from repro import configs
         from repro.configs.base import QuantConfig
+        from repro.launch.mesh import make_mesh
         from repro.models import model as MD
         from repro.models.serving import (build_variant_cache,
                                           quantize_params_for_serving)
@@ -358,8 +358,7 @@ def test_variant_cache_mesh_sharded():
         cfg = configs.reduced(configs.get_config("llama3-8b"))
         cfg = dataclasses.replace(cfg, quant=QuantConfig(mode="none"))
         params = MD.init_params(jax.random.PRNGKey(0), cfg)
-        mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4),
-                    ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cache = build_variant_cache(params, cfg, {2: (2.83, 3)}, mesh=mesh)
         direct = quantize_params_for_serving(params, cfg, r=2.83, act_bits=3)
 

@@ -182,3 +182,38 @@ def test_supervisor_restarts_after_injected_crash(tmp_path):
     assert float(final["value"]) == 10.0       # 5 from ckpt + steps 5..9
     assert final["steps_seen"] == [5, 6, 7, 8, 9]
     assert ck.latest_step(str(tmp_path)) == 10
+
+
+# ---------------------------------------------------------------------------
+# Launch plumbing: meshes and the compile cache
+# ---------------------------------------------------------------------------
+
+def test_meshes_have_auto_axes_and_refuse_a_ragged_model_axis():
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_local_mesh, make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    assert make_local_mesh(1).shape == {"data": 1, "model": 1}
+    with pytest.raises(ValueError, match="does not divide"):
+        make_local_mesh(2)
+
+
+def test_compile_cache_dir_is_the_env_var_or_fixed_in_the_checkout(
+        monkeypatch):
+    from repro import CHECKOUT_DIR
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.enable_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.enable_compile_cache() is None    # CPU: off
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.setattr(compile_cache.jax, "default_backend", lambda: "tpu")
+    try:
+        assert compile_cache.enable_compile_cache() == os.path.join(
+            CHECKOUT_DIR, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == \
+            compile_cache.DEFAULT_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
