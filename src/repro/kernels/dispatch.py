@@ -249,16 +249,18 @@ def _act_scalars(xf: Array, p: dict) -> tuple[Array, Array, Array]:
                 jnp.asarray(p["act_z"], jnp.float32).reshape(()),
                 n_lvl)
     act_lo = p.get("act_lo")
-    if act_lo is not None:
-        # export-frozen EMA calibration without the hoist (older
-        # artifacts): same zero-extended frozen-range convention as the
-        # QAT forward — one range convention everywhere
-        lo, hi = quant.act_range_bounds(
-            xf, jnp.asarray(act_lo, jnp.float32).reshape(()),
-            jnp.asarray(p["act_hi"], jnp.float32).reshape(()))
-    else:
-        lo, hi = quant.act_range_bounds(xf, include_zero=True)
-    s, z = quant.affine_scale_zp(lo, hi, n_lvl)
+    # named so a profiler trace can price the per-tensor range reduction
+    with jax.named_scope("act_range"):
+        if act_lo is not None:
+            # export-frozen EMA calibration without the hoist (older
+            # artifacts): same zero-extended frozen-range convention as the
+            # QAT forward — one range convention everywhere
+            lo, hi = quant.act_range_bounds(
+                xf, jnp.asarray(act_lo, jnp.float32).reshape(()),
+                jnp.asarray(p["act_hi"], jnp.float32).reshape(()))
+        else:
+            lo, hi = quant.act_range_bounds(xf, include_zero=True)
+        s, z = quant.affine_scale_zp(lo, hi, n_lvl)
     return s, z, n_lvl
 
 

@@ -183,12 +183,16 @@ def decode_ssm(x: Array, st: SSMState, p: dict, cfg: ModelConfig
     xh = xs.reshape(xs.shape[0], h, p_dim).astype(jnp.float32)
     dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32))      # (B, H)
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
-    dec = jnp.exp(dtv * a[None, :])                          # (B, H)
-    bv = b_ssm[:, 0].astype(jnp.float32)                     # (B, N)
-    cv = c_ssm[:, 0].astype(jnp.float32)
-    upd = jnp.einsum("bh,bhp,bn->bhpn", dtv, xh, bv)
-    state = st.state * dec[:, :, None, None] + upd
-    y = jnp.einsum("bhpn,bn->bhp", state, cv)
+    # the recurrent update reads and writes the whole f32 state: named so
+    # a profiler trace can price it (on TPU the name stays on the y read;
+    # the state's write fuses under the layer scan's stacking and loses it)
+    with jax.named_scope("ssm_state"):
+        dec = jnp.exp(dtv * a[None, :])                      # (B, H)
+        bv = b_ssm[:, 0].astype(jnp.float32)                 # (B, N)
+        cv = c_ssm[:, 0].astype(jnp.float32)
+        upd = jnp.einsum("bh,bhp,bn->bhpn", dtv, xh, bv)
+        state = st.state * dec[:, :, None, None] + upd
+        y = jnp.einsum("bhpn,bn->bhp", state, cv)
     y = y + p["d_skip"][None, :, None] * xh
     y = y.reshape(x.shape[0], 1, d_inner).astype(x.dtype)
     y = y * jax.nn.silu(z)

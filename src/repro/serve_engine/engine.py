@@ -13,6 +13,14 @@ claim after serving mixed traffic.
 The engine interleaves *lanes* (one per in-flight wave) round-robin, one
 decode step each — so a 2-bit lane and a 6-bit lane genuinely alternate
 operating points between decode steps of a single process.
+
+The serving calls are always instrumented: host spans
+(``jax.profiler.TraceAnnotation``, named ``serve.*`` and carrying the wave's
+``seq`` and rung) land on the device trace's clock whenever a profiler runs,
+and cost about a microsecond when none does. The device side names the
+jitted step ``decode_step`` (module ``jit_decode_step``) and scopes inside it
+(``ssm_state``, ``act_range``). The counters ``decode_steps``,
+``prefill_steps`` and ``live_rows`` are reported by ``describe()``.
 """
 from __future__ import annotations
 
@@ -63,6 +71,12 @@ class Lane:
 
 
 _Lane = Lane                  # pre-fleet private name (back-compat)
+
+
+def _span(name: str, wave: Wave):
+    """A ``serve.*`` host span of ``wave`` in the profiler's trace."""
+    return jax.profiler.TraceAnnotation(name, wave=wave.seq,
+                                        rung=wave.rung.bits)
 
 
 class ServeEngine:
@@ -217,11 +231,19 @@ class ServeEngine:
                 and dispatch.parse_backend(backend)[0] != "ref":
             self._autotune_projections()
         self._frontend_kwargs_fn = frontend_kwargs_fn
-        self._step = jax.jit(lambda p, s, t: MD.decode_step(p, cfg, s, t))
+
+        def decode_step(p, s, t):
+            return MD.decode_step(p, cfg, s, t)
+
+        # a named function, so the trace names its module jit_decode_step
+        self._step = jax.jit(decode_step)
         self.scheduler = Scheduler(self.ladder, self.max_batch)
         self.compilations_after_warmup: Optional[int] = None
         self.steps_by_rung = {op.bits: 0 for op in self.ladder}
         self.rung_switches = 0
+        self.decode_steps = 0         # step_lane calls that ran a step
+        self.prefill_steps = 0        # teacher-forced prompt positions
+        self.live_rows = 0            # decode-step rows still wanting a token
         self._last_step_bits: Optional[int] = None
         self._macs_by_ctx: dict[int, Any] = {}   # macs_per_token memo
 
@@ -325,14 +347,6 @@ class ServeEngine:
         v = self.cfg.vocab_size
         return jnp.argmax(logits[:, :, :v], axis=-1).astype(jnp.int32)
 
-    def _teacher_force(self, bits: int, state, prompts):
-        """Feed a (max_batch, L) prefix token by token; return the logits of
-        the final position and the threaded state."""
-        logits = None
-        for i in range(prompts.shape[1]):
-            logits, state = self._run_step(bits, state, prompts[:, i:i + 1])
-        return logits, state
-
     def _pad_rows(self, rows: np.ndarray) -> np.ndarray:
         """Pad the request dim to max_batch (repeating row 0) so every wave
         presents identical avals to the jitted step."""
@@ -371,10 +385,19 @@ class ServeEngine:
             raise ValueError(
                 f"prompt_len {reqs[0].prompt_len} + gen {gen_max} exceeds "
                 f"engine max_len {self.max_len}")
-        rows = jnp.asarray(self._pad_rows(rows), jnp.int32)
-        state = self._init_state(wave.rung.bits)
-        logits, state = self._teacher_force(wave.rung.bits, state, rows)
-        tok = self._greedy(logits)
+        bits = wave.rung.bits
+        with _span("serve.prefill", wave):
+            rows = jnp.asarray(self._pad_rows(rows), jnp.int32)
+            with _span("serve.init_state", wave):
+                state = self._init_state(bits)
+            # teacher forcing: the prefix token by token through the step
+            for i in range(rows.shape[1]):
+                with _span("serve.dispatch", wave):
+                    logits, state = self._run_step(bits, state,
+                                                   rows[:, i:i + 1])
+            self.prefill_steps += rows.shape[1]
+            with _span("serve.sample", wave):
+                tok = self._greedy(logits)
         return Lane(wave=wave, state=state, tok=tok, generated=[tok],
                     steps_left=gen_max - done - 1, done=done)
 
@@ -386,11 +409,21 @@ class ServeEngine:
         power-cap admission (each call costs the wave one token per
         request at its rung's bit-flip price)."""
         if lane.steps_left > 0:
-            logits, lane.state = self._run_step(
-                lane.wave.rung.bits, lane.state, lane.tok)
-            lane.tok = self._greedy(logits)
-            lane.generated.append(lane.tok)
-            lane.steps_left -= 1
+            wave = lane.wave
+            with _span("serve.step", wave):
+                # rows whose request still wants a token: the lane holds
+                # done + len(generated) tokens of each row so far
+                have = lane.done + len(lane.generated)
+                self.live_rows += sum(r.max_new_tokens > have
+                                      for r in wave.requests)
+                self.decode_steps += 1
+                with _span("serve.dispatch", wave):
+                    logits, lane.state = self._run_step(
+                        wave.rung.bits, lane.state, lane.tok)
+                with _span("serve.sample", wave):
+                    lane.tok = self._greedy(logits)
+                lane.generated.append(lane.tok)
+                lane.steps_left -= 1
         return lane.steps_left <= 0
 
     def _rung_tree(self, rung) -> pol.PolicyTree:
@@ -524,21 +557,17 @@ class ServeEngine:
                                f"ladder has {sorted(self.rungs)}")
         segments = []
         for bits, n in schedule:
-            if n <= 0:
-                segments.append({"rung_bits": bits, "tokens": []})
-                continue
-            rows = jnp.asarray(
-                self._pad_rows(np.asarray(prefix, np.int32)[None, :]),
-                jnp.int32)
-            state = self._init_state(bits)
-            logits, state = self._teacher_force(bits, state, rows)
             seg = []
-            tok = self._greedy(logits)
-            seg.append(int(np.asarray(tok)[0, 0]))
-            for _ in range(n - 1):
-                logits, state = self._run_step(bits, state, tok)
-                tok = self._greedy(logits)
-                seg.append(int(np.asarray(tok)[0, 0]))
+            if n > 0:
+                # a one-request wave through the serving calls generate()
+                # makes, so the stream gets the same spans and counters
+                req = Request(uid=0, prompt=np.asarray(prefix, np.int32),
+                              max_new_tokens=n)
+                lane = self.prefill_wave(Wave(rung=self.rungs[bits],
+                                              requests=(req,)))
+                while not self.step_lane(lane):
+                    pass
+                seg = lane.generated_rows()[0].tolist()
             prefix.extend(seg)
             segments.append({"rung_bits": bits, "tokens": seg})
         return {"tokens": prefix[prompt_len:], "segments": segments}
@@ -566,4 +595,7 @@ class ServeEngine:
             "compilations_after_warmup": self.compilations_after_warmup,
             "steps_by_rung": dict(self.steps_by_rung),
             "rung_switches": self.rung_switches,
+            "decode_steps": self.decode_steps,
+            "prefill_steps": self.prefill_steps,
+            "live_rows": self.live_rows,
         }

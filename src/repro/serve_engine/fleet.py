@@ -536,8 +536,7 @@ class Fleet:
                     rec.switches += 1
                     rec.rung_bits = new_rung.bits
                 self._replays[i] = dataclasses.replace(
-                    rp, wave=Wave(rung=new_rung,
-                                  requests=rp.wave.requests))
+                    rp, wave=dataclasses.replace(rp.wave, rung=new_rung))
         for host in self._alive_decode_hosts():
             for lane in list(host.lanes):
                 if lane.wave.rung.bits <= ceiling:
@@ -552,7 +551,7 @@ class Fleet:
                     continue
                 prefix = self._detach_lane(lane, switch_to=new_rung.bits)
                 self._replays.append(_Replay(
-                    wave=Wave(rung=new_rung, requests=lane.wave.requests),
+                    wave=dataclasses.replace(lane.wave, rung=new_rung),
                     prefix_rows=prefix, pinned_host=None, reason="switch"))
                 self.migrations += 1
 

@@ -14,6 +14,7 @@ import dataclasses
 from collections import OrderedDict, deque
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.serve_engine.ladder import OperatingPoint, select_rung
@@ -45,9 +46,13 @@ class Response:
 
 @dataclasses.dataclass(frozen=True)
 class Wave:
-    """A schedulable unit: requests sharing a rung and a prompt length."""
+    """A schedulable unit: requests sharing a rung and a prompt length.
+    ``seq`` is the scheduler's running number of the wave (-1 for a wave
+    built outside ``Scheduler.next_wave``); the engine's profiler spans
+    carry it, so every span of one wave shares an identifier."""
     rung: OperatingPoint
     requests: tuple
+    seq: int = -1
 
 
 class Scheduler:
@@ -58,6 +63,7 @@ class Scheduler:
             (op.bits, deque()) for op in self.ladder)
         self._rungs = {op.bits: op for op in self.ladder}
         self._rr = 0                      # round-robin cursor over rung index
+        self._seq = 0                     # number of the next wave formed
 
     def submit(self, req: Request,
                rung: Optional[OperatingPoint] = None) -> OperatingPoint:
@@ -100,6 +106,13 @@ class Scheduler:
         blocking the others, and the round-robin cursor only advances past
         rungs that actually produced work.
         """
+        with jax.profiler.TraceAnnotation("serve.next_wave") as span:
+            wave = self._form_wave(eligible_bits)
+            if wave is not None:
+                span.set_metadata(wave=wave.seq, rung=wave.rung.bits)
+        return wave
+
+    def _form_wave(self, eligible_bits: Optional[set]) -> Optional[Wave]:
         n = len(self.ladder)
         for off in range(n):
             bits = self.ladder[(self._rr + off) % n].bits
@@ -121,5 +134,7 @@ class Scheduler:
             rest.extend(q)
             q.clear()
             q.extend(rest)
-            return Wave(rung=self._rungs[bits], requests=tuple(picked))
+            self._seq += 1
+            return Wave(rung=self._rungs[bits], requests=tuple(picked),
+                        seq=self._seq - 1)
         return None
