@@ -4,7 +4,8 @@ The fused-prologue kernels (``pann_matmul_act`` / ``pann_matmul_packed_act``)
 are shape-sensitive in two ways the old one-size heuristic was not: the
 persistent VMEM codes panel costs ``bm * K`` bytes (large-K projections want
 a smaller bm), and the multi-buffered plane slots cost
-``depth * 2 * bk * bn`` (unpacked) or ``depth * 2 * bk * bn / 8`` (packed).
+``depth * 2 * bk * bn`` (unpacked: one plane a slot) or
+``depth * 2 * P * bk * bn / 8`` (packed: a grid step's P planes a slot).
 Beyond block shapes, the kernels expose two schedule knobs the tuner
 searches: the DMA pipeline depth (VMEM slots per plane stream) and the grid
 iteration order ('mnk' row-panel-outer vs 'nmk' N-outer). This module owns
@@ -39,7 +40,10 @@ atomically; a corrupt or foreign-version file is ignored, never crashed on.
 Version history: v1 stored bare [bm, bn, bk] triples; v2 adds the schedule
 knobs ({"blocks", "depth", "order"}) and the planes_active key segment;
 v3 entries hold only whole (32, 128) tiles — a v2 entry may hold a smaller
-block, which the TPU compiler refuses.
+block, which the TPU compiler refuses; v4 drops the packed winners that
+were measured on the per-bit plane unpack, whose costs the word-wide
+rebuild no longer has (and whose ``depth`` counted planes, not grid
+steps).
 """
 from __future__ import annotations
 
@@ -51,8 +55,9 @@ from typing import Callable, Iterable, NamedTuple, Optional
 import jax
 
 from repro import CHECKOUT_DIR
+from repro.kernels.pann_matmul_packed import K_GROUP
 
-CACHE_VERSION = 3
+CACHE_VERSION = 4
 
 _ENV_VAR = "REPRO_AUTOTUNE_CACHE"
 
@@ -154,13 +159,19 @@ def clear_memory_cache() -> None:
 
 
 def vmem_bytes(bm: int, bn: int, bk: int, k: int, packed: bool,
-               depth: int = 2) -> int:
-    """VMEM working set of the fused-prologue kernels for one grid step."""
-    plane_tile = (bk // 8) * bn if packed else bk * bn
+               depth: int = 2, planes: int = 7) -> int:
+    """VMEM working set of the fused-prologue kernels for one grid step.
+    The fused kernel's slots hold one int8 plane each and it rebuilds w
+    as int32; the packed kernel's slots hold a grid step's ``planes``
+    packed planes and it rebuilds w as int8."""
+    if packed:
+        slot, w = planes * (bk // 8) * bn, bk * bn
+    else:
+        slot, w = bk * bn, 4 * bk * bn
     return (4 * bm * bk            # fp32 x landing pad
             + bm * k               # persistent int8 codes panel
-            + depth * 2 * plane_tile   # DMA slots x 2 signs
-            + 4 * bk * bn          # reconstructed-w int32 scratch
+            + depth * 2 * slot     # DMA slots x 2 signs
+            + w                    # reconstructed-w scratch
             + 4 * bm * bn          # int32 accumulator
             + 4 * bm * bn)         # f32 output block
 
@@ -172,6 +183,9 @@ def vmem_bytes(bm: int, bn: int, bk: int, k: int, packed: bool,
 # refuses.
 ROW_TILE = 32
 LANE_TILE = 128
+# the packed kernel's widest N and K blocks
+PACKED_BN_CAP = 512
+PACKED_BK_CAP = 2048
 
 
 def row_block(m: int, cap: int = 128) -> int:
@@ -186,18 +200,43 @@ def lane_block(k: int, cap: int) -> int:
     return min(cap, -(-k // LANE_TILE) * LANE_TILE)
 
 
+def divisor_block(extent: int, tile: int, cap: int) -> int:
+    """The largest multiple of ``tile``, at most ``cap``, that divides
+    ``extent`` rounded up to ``tile``: a block that never makes dispatch
+    pad the weight planes beyond that round-up (a padded copy of a
+    projection's planes costs as much HBM traffic as the matmul)."""
+    units = -(-extent // tile)
+    return tile * max(d for d in range(1, max(cap // tile, 1) + 1)
+                      if units % d == 0)
+
+
 def heuristic_blocks(m: int, n: int, k: int, planes: int = 7,
                      packed: bool = False,
                      vmem_budget: int = 8 * 2 ** 20) -> tuple[int, int, int]:
     """Deterministic default: MXU-aligned blocks shrunk until the act-kernel
     working set fits the VMEM budget (bk first — cheapest to shrink — then
-    bm, whose cost is dominated by the bm*K codes panel)."""
+    bm, whose cost is dominated by the bm*K codes panel). The packed
+    kernel's blocks are as large as its caps allow without padding the
+    planes: its operand rebuild is cheap, so a grid step's fixed cost is
+    what a larger block amortizes, and its K block holds whole 256-row
+    rebuild groups."""
     bm = row_block(m)
-    bn = lane_block(n, 128)
-    bk = lane_block(k, 512)
-    while bk > LANE_TILE and vmem_bytes(bm, bn, bk, k, packed) > vmem_budget:
-        bk = lane_block(bk // 2, 512)
-    while bm > ROW_TILE and vmem_bytes(bm, bn, bk, k, packed) > vmem_budget:
+
+    def fits(bm, bk):
+        return vmem_bytes(bm, bn, bk, k, packed, planes=planes) \
+            <= vmem_budget
+
+    if packed:
+        bn = divisor_block(n, LANE_TILE, PACKED_BN_CAP)
+        bk = divisor_block(k, K_GROUP, PACKED_BK_CAP)
+        while bk > K_GROUP and not fits(bm, bk):
+            bk = divisor_block(k, K_GROUP, bk - K_GROUP)
+    else:
+        bn = lane_block(n, LANE_TILE)
+        bk = lane_block(k, 512)
+        while bk > LANE_TILE and not fits(bm, bk):
+            bk = lane_block(bk // 2, 512)
+    while bm > ROW_TILE and not fits(bm, bk):
         bm = row_block(bm // 2)
     return bm, bn, bk
 
@@ -251,7 +290,8 @@ def candidate_blocks(m: int, n: int, k: int, planes: int,
     for bm in bms:
         for bn in bns:
             for bk in bks:
-                if vmem_bytes(bm, bn, bk, k, packed) <= vmem_budget:
+                if vmem_bytes(bm, bn, bk, k, packed,
+                              planes=planes) <= vmem_budget:
                     out.add((bm, bn, bk))
     return sorted(out)
 
@@ -264,7 +304,8 @@ def candidate_params(m: int, n: int, k: int, planes: int,
     out = {heuristic_params(m, n, k, planes, packed, vmem_budget)}
     for bm, bn, bk in candidate_blocks(m, n, k, planes, packed, vmem_budget):
         for depth in DMA_DEPTHS:
-            if vmem_bytes(bm, bn, bk, k, packed, depth) > vmem_budget:
+            if vmem_bytes(bm, bn, bk, k, packed, depth,
+                          planes) > vmem_budget:
                 continue
             for order in GRID_ORDERS:
                 out.add(KernelParams(bm, bn, bk, depth, order))

@@ -12,7 +12,8 @@ through ``models.layers.apply_linear``):
   ``packed``  Pallas packed-plane matmul (``kernels/pann_matmul_packed``):
               reads the bit-packed ``w_planes_pos``/``w_planes_neg`` artifact
               leaves (8 codes/byte along K — 2*P/8 bytes/weight HBM for plane
-              count P = the module's b_R), unpacking in VMEM.
+              count P, 1.75 at the ladder's P = 7), rebuilding the int8
+              operand in VMEM with word-wide bit arithmetic.
 
 The Pallas backends use the FUSED-PROLOGUE kernels (``pann_matmul_act`` /
 ``pann_matmul_packed_act``): fp32 activations go straight into the kernel,
@@ -192,7 +193,10 @@ def _matmul_fused(xf: Array, w_q: Array, s, z, n_lvl, gamma: Array,
 def _matmul_packed(xf: Array, pp: Array, pn: Array, s, z, n_lvl,
                    gamma: Array, zcol: Array, interpret: bool, shift=None,
                    params: autotune.KernelParams | None = None) -> Array:
-    """Fused-prologue packed-plane kernel on the uint8 artifact leaves."""
+    """Fused-prologue packed-plane kernel on the uint8 artifact leaves.
+    The kernel rebuilds the weight rows in a K order permuted within each
+    256-row group (``pann_matmul_packed.permute_k``); x's columns are
+    permuted to match where they are padded."""
     m, k = xf.shape
     k_full = pp.shape[-2] * 8        # pack_planes padded K up to 8
     n = pp.shape[-1]
@@ -200,10 +204,14 @@ def _matmul_packed(xf: Array, pp: Array, pn: Array, s, z, n_lvl,
     if params is None:
         params = autotune.params_for(m, k_full, n, n_planes, "packed")
     bm, bn, bk = params.blocks
-    bk = _pick_bk(bk, 8)             # the packed kernel needs bk % 8 == 0
+    bk = _pick_bk(bk, _pk.K_GROUP)   # whole 256-row reconstruct groups
+    bn = -(-bn // 128) * 128         # whole 128-lane plane chunks
     xp = ops._pad_to(ops._pad_to(xf, bm, 0), k_full, 1)
-    xp = ops._pad_to(xp, bk, 1)
+    xp = _pk.permute_k(ops._pad_to(xp, bk, 1))
     k_pad = xp.shape[1]
+    # a padded copy of the planes is made only where N or K is not a
+    # multiple of its block (the heuristic's blocks avoid that wherever N
+    # is a multiple of 128: the TPU compiler refuses a shorter copy)
     ppp = ops._pad_to(ops._pad_to(pp, k_pad // 8, 1), bn, 2)
     pnp = ops._pad_to(ops._pad_to(pn, k_pad // 8, 1), bn, 2)
     gp = ops._pad_to(gamma, bn, 0)

@@ -59,9 +59,12 @@ def _scalars(spec):
     return f32, f32, f32, f32           # s, z, n_lvl, plane_shift
 
 
-# zamba2-1.2b: d_model 2048, shared-block MLP d_ff 8192 (up and down);
+# zamba2-1.2b: d_model 2048, shared-block MLP d_ff 8192 (up and down), the
+# LM head over the 32000-token vocabulary, the Mamba2 in-projection (two
+# 4096-wide streams, B, C and 64 head gates: 8384) and out-projection;
 # and widths under one lane tile (a reduced config), which dispatch pads
-WIDTHS = [(2048, 8192), (8192, 2048), (64, 48)]
+WIDTHS = [(2048, 8192), (8192, 2048), (64, 48), (2048, 32000),
+          (2048, 8384), (4096, 2048)]
 
 
 @pytest.mark.parametrize("k,n", WIDTHS)

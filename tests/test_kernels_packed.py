@@ -1,12 +1,14 @@
 """Packed bit-plane PANN kernel vs oracle + pack/unpack roundtrip."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import pann as pann_core
 from repro.core.unsigned import unsigned_split
-from repro.kernels import ref
+from repro.kernels import dispatch, ref
 from repro.kernels.pann_matmul_packed import (pack_planes, pann_matmul_packed,
+                                              permute_k, swar_tile,
                                               unpack_planes)
 
 RNG = np.random.default_rng(0)
@@ -97,3 +99,73 @@ def test_dtype_invariants():
     # float-typed {0,1} planes pack identically (quantizer output dtype)
     packed_f = pack_planes(planes.astype(jnp.float32))
     np.testing.assert_array_equal(np.asarray(packed_f), np.asarray(packed))
+
+
+# ---------------------------------------------------------------------------
+# word-wide (SWAR) rebuild of the int8 operand: bit-exact against the oracle
+# ---------------------------------------------------------------------------
+
+def _codes(kind: str, k: int, n: int) -> np.ndarray:
+    """int codes in [-127, 127]: random, or the extremes, where every plane
+    of one sign is set (a byte carry or borrow would show there)."""
+    if kind == "random":
+        return RNG.integers(-127, 128, (k, n))
+    return np.where(RNG.integers(0, 2, (k, n)) == 1, 127, -127)
+
+
+def _packed_leaves(codes: np.ndarray):
+    pos, neg = unsigned_split(jnp.asarray(codes, jnp.int32))
+    return (pack_planes(pann_core.bitplane_decompose(pos, 7)),
+            pack_planes(pann_core.bitplane_decompose(neg, 7)))
+
+
+@pytest.mark.parametrize("shift", [None, 0, 3, 6])
+@pytest.mark.parametrize("kind", ["random", "extremes"])
+def test_swar_rebuild_with_permuted_x_is_the_int_matmul(kind, shift):
+    """Outside any kernel: the rebuilt int8 rows, dotted with x's columns
+    in ``permute_k`` order, equal x_q @ w_q in int32 (dead planes below
+    ``shift`` masked as ``masked_codes`` masks them)."""
+    k, n = 512, 256
+    codes = _codes(kind, k, n)
+    pp, pn = _packed_leaves(codes)
+    w8 = jax.jit(swar_tile, static_argnums=2)(pp, pn, shift)
+    assert w8.shape == (k, n) and w8.dtype == jnp.int8
+    x_q = jnp.asarray(RNG.integers(0, 128, (8, k)), jnp.int32)
+    got = jnp.dot(permute_k(x_q), w8.astype(jnp.int32))
+    w = jnp.asarray(codes, jnp.int32)
+    if shift is not None:
+        w = pann_core.masked_codes(w, shift)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(x_q @ w))
+
+
+def _dispatch_case(m, k, n, kind, shift):
+    codes = _codes(kind, k, n)
+    pp, pn = _packed_leaves(codes)
+    xf = jnp.asarray(RNG.standard_normal((m, k)), jnp.float32)
+    s, z, n_lvl = jnp.float32(0.03), jnp.float32(60.0), jnp.float32(127.0)
+    gamma = jnp.asarray(RNG.uniform(0.001, 0.01, (n,)), jnp.float32)
+    zcol = jnp.asarray(RNG.integers(-1000, 1000, (n,)), jnp.int32)
+    sh = None if shift is None else jnp.float32(shift)
+    got = dispatch._matmul_packed(xf, pp, pn, s, z, n_lvl, gamma, zcol,
+                                  interpret=True, shift=sh)
+    want = dispatch._dispatch_ref(xf, jnp.asarray(codes, jnp.int8), s, z,
+                                  n_lvl, gamma, zcol, sh)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("shift", range(7))
+def test_packed_dispatch_is_bit_exact_at_every_plane_shift(shift):
+    _dispatch_case(4, 512, 384, "random", shift)
+
+
+@pytest.mark.parametrize("shift", [0, 1])
+def test_packed_dispatch_is_bit_exact_at_the_code_extremes(shift):
+    _dispatch_case(16, 512, 384, "extremes", shift)
+
+
+@pytest.mark.parametrize("m,k,n", [(4, 2048, 8192), (16, 2048, 8192),
+                                   (16, 1000, 8384)])
+def test_packed_dispatch_is_bit_exact_at_serving_widths(m, k, n):
+    """Decode row counts at zamba2 widths, and a K that is not a multiple
+    of 256 with an N that no 512-lane block divides."""
+    _dispatch_case(m, k, n, "random", 1)
