@@ -1,5 +1,7 @@
 """rwkv6-1.6b "Finch" [ssm]: 24L d_model=2048 (attention-free, data-dependent
-decay) d_ff=7168 vocab=65536. [arXiv:2404.05892; unverified]"""
+decay and token shift) d_ff=7168 vocab=65536, heads of 64, mix-LoRA rank 32,
+decay-LoRA rank 64 (models/rwkv.py). [arXiv:2404.05892; the shapes of the
+released checkpoint RWKV/v6-Finch-1B6-HF]"""
 from repro.configs.base import ModelConfig
 
 

@@ -11,6 +11,7 @@ import dataclasses
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import power as pw
 from repro.core.power import MacBreakdown
+from repro.models import rwkv as R
 from repro.models.transformer import group_layout
 
 
@@ -39,8 +40,12 @@ def _ssm_params(cfg: ModelConfig) -> int:
 
 
 def _rwkv_params(cfg: ModelConfig) -> int:
+    """The published block's matrices: five time-mix projections, the
+    token-shift and decay LoRAs, the channel mix with its receptance."""
     d = cfg.d_model
-    return 5 * d * d + d * 64 + 64 * d + 2 * d * cfg.d_ff
+    mix = 5 * R.MIX_RANK
+    return 6 * d * d + 2 * d * mix + 2 * d * R.DECAY_RANK \
+        + 2 * d * cfg.d_ff
 
 
 def _layer_params(cfg: ModelConfig, kind: str) -> int:
@@ -207,10 +212,13 @@ def module_cost_profile(cfg: ModelConfig) -> tuple[ModuleCost, ...]:
     def add_rwkv(count: float = 1.0) -> None:
         for name in ("wr", "wk", "wv", "wg", "wo"):
             add(f"rwkv.tm.{name}", d, d, count)
-        add("rwkv.tm.decay_a", d, 64, count)
-        add("rwkv.tm.decay_b", 64, d, count)
+        add("rwkv.tm.maa_w1", d, 5 * R.MIX_RANK, count)
+        add("rwkv.tm.maa_w2", R.MIX_RANK, d, 5 * count)   # five calls
+        add("rwkv.tm.decay_a", d, R.DECAY_RANK, count)
+        add("rwkv.tm.decay_b", R.DECAY_RANK, d, count)
         add("rwkv.cm.wk", d, cfg.d_ff, count)
         add("rwkv.cm.wv", cfg.d_ff, d, count)
+        add("rwkv.cm.wr", d, d, count)
 
     pattern, n_groups, n_tail = group_layout(cfg)
     seq = [s.kind for s in pattern] * n_groups \

@@ -30,7 +30,10 @@ and ``models/serving.py``):
   moe.router moe.w_gate moe.w_up moe.w_down  (MoE router + experts)
   ssm.in_proj ssm.out_proj ssm.conv          (Mamba2)
   rwkv.tm.wr rwkv.tm.wk rwkv.tm.wv rwkv.tm.wg rwkv.tm.decay_a
-  rwkv.tm.decay_b rwkv.tm.wo rwkv.cm.wk rwkv.cm.wv
+  rwkv.tm.decay_b rwkv.tm.maa_w1 rwkv.tm.maa_w2 rwkv.tm.wo
+  rwkv.cm.wk rwkv.cm.wv rwkv.cm.wr           (RWKV-6: maa_w1/maa_w2 are the
+                                              token-shift LoRA, maa_w2 one
+                                              role for its five calls)
   lm_head
   conv.s0 conv.s1 ...                        (modality-frontend conv stem
                                               layers, one role per depth —

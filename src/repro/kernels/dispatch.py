@@ -71,6 +71,7 @@ from repro.kernels import pann_conv as _pc
 from repro.kernels import pann_matmul as _pm
 from repro.kernels import pann_matmul_packed as _pk
 from repro.kernels import ref as _ref
+from repro.kernels import rwkv_wkv as _wkv
 
 Array = jax.Array
 
@@ -526,6 +527,24 @@ def decode_attention(q: Array, kv, backend, *, num_kv_heads: int,
                                         softcap=softcap)
     # exit barrier: the _dispatch_rows contract
     return jax.lax.optimization_barrier(out).reshape(b, h, hd)
+
+
+def wkv_decode(r: Array, k: Array, v: Array, w: Array, u: Array,
+               state: Array, backend) -> tuple[Array, Array]:
+    """One RWKV-6 wkv decode step, the recurrence's dispatch point.
+
+    r, k, v, w: (B, H, hd) — receptance, key, value and the decay in
+    (0, 1); u: (H, hd), the bonus; ``state``: (B, hd, H * hd) f32 in the
+    value-major layout of ``kernels.rwkv_wkv``. Returns (o (B, H, hd) f32,
+    the new state). 'fused'/'packed' run the Pallas kernel on TPU (or when
+    ':force'd, in interpret mode) and 'ref' the jnp oracle, as in
+    ``decode_attention``; both compute in f32 at full precision."""
+    name, force = parse_backend(backend or "ref")
+    f32 = jnp.float32
+    args = tuple(a.astype(f32) for a in (r, k, v, w, u, state))
+    if name != "ref" and (ops.on_tpu() or force):
+        return _wkv.wkv_decode(*args, interpret=not ops.on_tpu())
+    return _ref.wkv_decode_ref(*args)
 
 
 # ---------------------------------------------------------------------------

@@ -162,3 +162,30 @@ def decode_attention_ref(qq: Array, q_z: Array, q_scale: Array,
     scale = sv_ref / prob_scale                             # (B,)
     return ((pv - corr[..., None]).astype(jnp.float32)
             * scale[:, None, None, None])
+
+
+# ---------------------------------------------------------------------------
+# RWKV-6 wkv decode step (kernels/rwkv_wkv.py)
+# ---------------------------------------------------------------------------
+
+def wkv_sum(t: Array) -> Array:
+    """Sum over the last axis (a power of two) by halving: element i adds
+    element i + n/2, then i + n/4, ... — the order of the wkv kernel."""
+    while t.shape[-1] > 1:
+        half = t.shape[-1] // 2
+        t = t[..., :half] + t[..., half:]
+    return t[..., 0]
+
+
+def wkv_decode_ref(r: Array, k: Array, v: Array, w: Array, u: Array,
+                   state: Array) -> tuple[Array, Array]:
+    """Oracle for kernels.rwkv_wkv.wkv_decode, to the bit: r, k, v, w (B,
+    H, hd), u (H, hd), value-major state (B, hd, H * hd) holding S[h, i, j]
+    at [j, h * hd + i]. Returns (o (B, H, hd), new state)."""
+    b, h, hd = r.shape
+    s = state.astype(jnp.float32).reshape(b, hd, h, hd)       # [b, j, h, i]
+    kv = k[:, None] * jnp.swapaxes(v, 1, 2)[..., None]        # k[i] v[j]
+    t = r[:, None] * (s + u[None, None] * kv)
+    o = jnp.swapaxes(wkv_sum(t), 1, 2)                        # (B, H, hd)
+    s_new = w[:, None] * s + kv
+    return o, s_new.reshape(b, hd, h * hd)

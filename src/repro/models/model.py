@@ -61,6 +61,8 @@ def init_params(key, cfg: ModelConfig) -> dict:
                                           cfg.padded_vocab, scale=0.02)
     if cfg.family == "hybrid":
         params["shared_attn"] = T.init_shared_attn(ks[3], cfg)
+    if cfg.family == "ssm":      # RWKV-6: LayerNorm of the embedding
+        params["ln0"] = L.init_norm(cfg.d_model, "layernorm")
     if cfg.family == "encdec":
         params["encoder"] = _init_stack(ks[4], cfg, cfg.encoder_layers,
                                         role="encoder")
@@ -189,6 +191,20 @@ def encode(params: dict, cfg: ModelConfig, inputs: Array) -> Array:
     return x
 
 
+def _ln0(x: Array, params: dict) -> Array:
+    """RWKV-6's LayerNorm of the embedding, where the params hold it."""
+    if "ln0" not in params:
+        return x
+    return L.apply_norm(x, params["ln0"], "layernorm")
+
+
+def _embed(params: dict, cfg: ModelConfig, tokens: Array, dtype) -> Array:
+    x = C.constrain_batch(L.embed(tokens, params["embed"], dtype))
+    if cfg.scale_embed:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, dtype)
+    return _ln0(x, params)
+
+
 class ForwardOut(NamedTuple):
     logits: Array
     aux_loss: Array
@@ -208,9 +224,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: Array, *,
     observed ranges for the EMA update (power-aware QAT, DESIGN.md §9)."""
     dtype = _dtype(cfg)
     collect = bool(calib)
-    x = C.constrain_batch(L.embed(tokens, params["embed"], dtype))
-    if cfg.scale_embed:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dtype)
+    x = _embed(params, cfg, tokens, dtype)
 
     obs = CAL.unseen_like(calib) if collect else None
     cross_src = None
@@ -336,9 +350,7 @@ def decode_step(params: dict, cfg: ModelConfig, state: DecodeState,
     dtype = _dtype(cfg)
     pattern, _, _ = T.group_layout(cfg)
     shared = params.get("shared_attn")
-    x = C.constrain_batch(L.embed(tokens, params["embed"], dtype))
-    if cfg.scale_embed:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dtype)
+    x = _embed(params, cfg, tokens, dtype)
 
     def group_body(h, xs):
         gp, gcache, gcross = xs

@@ -34,7 +34,8 @@ from repro.kernels.pann_matmul_packed import pack_planes
 # projection parents whose "w" is PANN-quantized for serving
 _QUANT_PARENTS = {
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "in_proj",
-    "out_proj", "wr", "wg", "decay_a", "decay_b", "lm_head",
+    "out_proj", "wr", "wg", "decay_a", "decay_b", "maa_w1", "maa_w2",
+    "lm_head",
 }
 
 

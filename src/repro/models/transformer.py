@@ -7,7 +7,7 @@ Layer kinds:
   attn_moe    — self-attention + MoE block
   mamba       — Mamba2 (SSD) block
   mamba_attn  — Mamba2 block followed by the zamba2 *shared* attn+MLP block
-  rwkv        — RWKV6 time-mix + channel-mix block
+  rwkv        — RWKV-6 "Finch" time-mix + channel-mix block
   cross_attn  — VLM / enc-dec cross-attention (+MLP) block
 """
 from __future__ import annotations
@@ -229,14 +229,15 @@ def decode_layer(x: Array, cache: Any, p: dict, cfg: ModelConfig,
             return x, (ssm_state, kv)
         return x, ssm_state
     if spec.kind == "rwkv":
-        h = L.apply_norm(x, p["norm1"], cfg.norm)
-        y, wkv, last_tm = R.apply_time_mix(h, p["tm"], cfg, state=cache)
-        x = x + y
-        h = L.apply_norm(x, p["norm2"], cfg.norm)
-        y, last_cm = R.apply_channel_mix(h, p["cm"], cfg, prev=cache.shift_cm)
-        x = x + y
-        new = R.RWKVState(wkv=wkv, shift_tm=last_tm.astype(cache.shift_tm.dtype),
-                          shift_cm=last_cm.astype(cache.shift_cm.dtype),
+        h_tm = L.apply_norm(x, p["norm1"], cfg.norm)[:, 0]
+        y, wkv = R.decode_time_mix(h_tm, p["tm"], cfg, cache)
+        x = x + y[:, None]
+        h_cm = L.apply_norm(x, p["norm2"], cfg.norm)[:, 0]
+        y = R.channel_mix(h_cm, cache.shift_cm.astype(h_cm.dtype), p["cm"],
+                          cfg)
+        x = x + y[:, None]
+        new = R.RWKVState(wkv=wkv, shift_tm=h_tm.astype(cache.shift_tm.dtype),
+                          shift_cm=h_cm.astype(cache.shift_cm.dtype),
                           length=cache.length + 1)
         return x, new
     raise ValueError(spec.kind)

@@ -19,8 +19,10 @@ The serving calls are always instrumented: host spans
 ``seq`` and rung) land on the device trace's clock whenever a profiler runs,
 and cost about a microsecond when none does. The device side names the
 jitted step ``decode_step`` (module ``jit_decode_step``) and scopes inside it
-(``ssm_state``, ``act_range``). The counters ``decode_steps``,
-``prefill_steps`` and ``live_rows`` are reported by ``describe()``.
+(``ssm_state``, ``act_range``, ``rwkv_shift``, ``rwkv_wkv``). The counters
+``decode_steps``, ``prefill_steps`` and ``live_rows`` are reported by
+``describe()``, with ``recurrent_state_bytes``, the recurrent state one lane
+holds.
 """
 from __future__ import annotations
 
@@ -37,7 +39,9 @@ from repro.core import policy as pol
 from repro.core import power as pw
 from repro.kernels import dispatch
 from repro.models import model as MD
+from repro.models import rwkv as R
 from repro.models import serving
+from repro.models import ssm as S
 from repro.serve_engine.ladder import build_ladder, select_rung
 from repro.serve_engine.scheduler import Request, Response, Scheduler, Wave
 
@@ -246,6 +250,24 @@ class ServeEngine:
         self.live_rows = 0            # decode-step rows still wanting a token
         self._last_step_bits: Optional[int] = None
         self._macs_by_ctx: dict[int, Any] = {}   # macs_per_token memo
+        self.recurrent_state_bytes = self._recurrent_state_bytes()
+
+    def _recurrent_state_bytes(self) -> int:
+        """Bytes of recurrent state one lane holds: the RWKV-6 wkv state
+        and token shifts, the Mamba2 state and conv tail (KV caches are not
+        recurrent state). From shapes alone; nothing is allocated."""
+        if self._frontend_kwargs_fn is not None:
+            return 0
+        shapes = jax.eval_shape(
+            lambda v: MD.init_decode_state(v, self.cfg, self.max_batch,
+                                           self.max_len),
+            next(iter(self.variants.values())))
+        kinds = (R.RWKVState, S.SSMState)
+        nodes = jax.tree_util.tree_leaves(
+            shapes.caches, is_leaf=lambda n: isinstance(n, kinds))
+        return int(sum(a.size * a.dtype.itemsize for n in nodes
+                       if isinstance(n, kinds)
+                       for a in jax.tree_util.tree_leaves(n)))
 
     # -- offline autotuning -------------------------------------------------
 
@@ -598,4 +620,5 @@ class ServeEngine:
             "decode_steps": self.decode_steps,
             "prefill_steps": self.prefill_steps,
             "live_rows": self.live_rows,
+            "recurrent_state_bytes": self.recurrent_state_bytes,
         }

@@ -1,4 +1,5 @@
-"""The serving kernels compile for TPU v5e at zamba2-1.2b widths.
+"""The serving kernels compile for TPU v5e at zamba2-1.2b and RWKV-6 Finch
+1.6B widths.
 
 Each test compiles one kernel, through the dispatch code that pads and
 blocks its operands, for a described ``v5e:2x2`` topology: the TPU compiler
@@ -65,9 +66,15 @@ def _scalars(spec):
 # and widths under one lane tile (a reduced config), which dispatch pads
 WIDTHS = [(2048, 8192), (8192, 2048), (64, 48), (2048, 32000),
           (2048, 8384), (4096, 2048)]
+# RWKV-6 Finch 1.6B (packed only): the time-mix projections and the
+# channel mix's receptance, the token-shift LoRA (2048 -> 160, then five
+# 32 -> 2048), the decay LoRA (2048 -> 64 -> 2048), the channel mix and
+# the LM head over 65536 tokens
+FINCH_WIDTHS = [(2048, 2048), (2048, 160), (32, 2048), (2048, 64),
+                (64, 2048), (2048, 7168), (7168, 2048), (2048, 65536)]
 
 
-@pytest.mark.parametrize("k,n", WIDTHS)
+@pytest.mark.parametrize("k,n", WIDTHS + FINCH_WIDTHS)
 def test_packed_matmul_compiles(spec, k, n):
     s, z, n_lvl, shift = _scalars(spec)
     planes = spec((PLANES, k // 8, n), jnp.uint8)
@@ -112,6 +119,24 @@ def test_decode_attention_compiles(spec):
                           planes, row, row, planes, row, row,
                           spec((), jnp.int32), f32, f32)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [16, 3])
+def test_wkv_decode_compiles(spec, rows):
+    """The RWKV-6 wkv decode kernel at Finch 1.6B's 32 heads of 64, under
+    its own name in the compiled program (the trace readers find it by
+    that name)."""
+    from repro.kernels import rwkv_wkv
+    h, hd = 32, 64
+    vec = spec((rows, h, hd), jnp.float32)
+
+    def f(*args):
+        return rwkv_wkv.wkv_decode(*args, interpret=False)
+
+    text = _compiled_text(f, vec, vec, vec, vec, spec((h, hd), jnp.float32),
+                          spec((rows, hd, h * hd), jnp.float32))
+    assert "tpu_custom_call" in text
+    assert f"%{rwkv_wkv.NAME}" in text
 
 
 def test_ref_projection_leaves_through_its_barrier(spec):

@@ -34,7 +34,7 @@ def test_known_full_sizes():
         "mixtral-8x7b": (46.7e9, 0.1),
         "dbrx-132b": (132e9, 0.12),
         "llama-3.2-vision-90b": (90e9, 0.25),  # includes cross-attn layers
-        "rwkv6-1.6b": (1.6e9, 0.25),
+        "rwkv6-1.6b": (1.6e9, 0.01),    # the published block's matrices
         "zamba2-1.2b": (1.2e9, 0.35),
     }
     for arch, (want, tol) in cases.items():

@@ -83,7 +83,9 @@ def _quantized_modules(qp):
                    "lm_head"}),
     ("zamba2-1.2b", {"ssm.in_proj", "ssm.out_proj", "attn.wq", "mlp.w_up"}),
     ("rwkv6-1.6b", {"rwkv.tm.wr", "rwkv.tm.wk", "rwkv.tm.decay_a",
-                    "rwkv.tm.wo", "rwkv.cm.wk", "rwkv.cm.wv"}),
+                    "rwkv.tm.decay_b", "rwkv.tm.maa_w1", "rwkv.tm.maa_w2",
+                    "rwkv.tm.wo", "rwkv.cm.wk", "rwkv.cm.wv",
+                    "rwkv.cm.wr"}),
 ])
 def test_every_module_role_bit_identical(arch, expect):
     cfg = _cfg(arch)
