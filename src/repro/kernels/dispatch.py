@@ -57,7 +57,10 @@ codes / zero planes (exact no-ops) and the result is sliced back.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -132,6 +135,47 @@ def resolve_backend(spec: str, p: dict) -> tuple[str, bool]:
     return name, not ops.on_tpu()
 
 
+class StackedPlanes(NamedTuple):
+    """A plane leaf that names its stack and its layer: ``stack`` is the
+    (L, P, K/8, N) uint8 store leaf of L stacked layers, ``index`` the
+    layer (an int or an int32 scalar). ``models.decode_step`` hands these
+    to the projections in place of sliced (P, K/8, N) leaves, and the
+    packed kernel reads the layer where it lies in the store."""
+    stack: Array
+    index: Array | int
+
+
+# the plane leaves of a packed artifact
+PLANE_KEYS = ("w_planes_pos", "w_planes_neg")
+
+# the tallies of the open ``plane_traffic`` contexts
+_PLANE_TALLIES: contextvars.ContextVar[tuple] = contextvars.ContextVar(
+    "plane_tallies", default=())
+
+
+@contextlib.contextmanager
+def plane_traffic():
+    """Tally the packed planes that the packed calls traced while the
+    context is open read: ``in_place`` the bytes the kernel reads from the
+    store leaf itself, ``copied`` those it reads through a sliced or
+    padded copy (K or N off its block). One layer's pos and neg planes
+    count once per call; counted from shapes at trace time, nothing is
+    added to the traced program."""
+    tally = {"in_place": 0, "copied": 0}
+    token = _PLANE_TALLIES.set(_PLANE_TALLIES.get() + (tally,))
+    try:
+        yield tally
+    finally:
+        _PLANE_TALLIES.reset(token)
+
+
+def _plane_layer_shape(leaf) -> tuple[int, ...]:
+    """(P, K/8, N) of one layer of a plane leaf, stacked or not."""
+    if isinstance(leaf, StackedPlanes):
+        return leaf.stack.shape[1:]
+    return leaf.shape
+
+
 def _pick_bk(bk: int, mult: int) -> int:
     """Largest multiple of ``mult`` <= bk (floor at ``mult``)."""
     return max(mult, bk - bk % mult)
@@ -191,17 +235,22 @@ def _matmul_fused(xf: Array, w_q: Array, s, z, n_lvl, gamma: Array,
     return y[:m, :n]
 
 
-def _matmul_packed(xf: Array, pp: Array, pn: Array, s, z, n_lvl,
-                   gamma: Array, zcol: Array, interpret: bool, shift=None,
+def _matmul_packed(xf: Array, pp, pn, s, z, n_lvl, gamma: Array,
+                   zcol: Array, interpret: bool, shift=None,
                    params: autotune.KernelParams | None = None) -> Array:
-    """Fused-prologue packed-plane kernel on the uint8 artifact leaves.
-    The kernel rebuilds the weight rows in a K order permuted within each
-    256-row group (``pann_matmul_packed.permute_k``); x's columns are
-    permuted to match where they are padded."""
+    """Fused-prologue packed-plane kernel on the uint8 artifact leaves:
+    (P, K/8, N) arrays, or ``StackedPlanes`` that name a layer of an
+    (L, P, K/8, N) store leaf. The kernel rebuilds the weight rows in a K
+    order permuted within each 256-row group
+    (``pann_matmul_packed.permute_k``); x's columns are permuted to match
+    where they are padded.
+
+    Where K and N are whole multiples of the blocks, the kernel reads the
+    planes where they lie, a stacked leaf at its layer; elsewhere the
+    layer is sliced out and padded (a copy of its planes every call)."""
     m, k = xf.shape
-    k_full = pp.shape[-2] * 8        # pack_planes padded K up to 8
-    n = pp.shape[-1]
-    n_planes = pp.shape[-3]
+    n_planes, k8, n = _plane_layer_shape(pp)
+    k_full = k8 * 8                  # pack_planes padded K up to 8
     if params is None:
         params = autotune.params_for(m, k_full, n, n_planes, "packed")
     bm, bn, bk = params.blocks
@@ -210,16 +259,25 @@ def _matmul_packed(xf: Array, pp: Array, pn: Array, s, z, n_lvl,
     xp = ops._pad_to(ops._pad_to(xf, bm, 0), k_full, 1)
     xp = _pk.permute_k(ops._pad_to(xp, bk, 1))
     k_pad = xp.shape[1]
+    in_place = k_pad == k_full and n % bn == 0
+    for tally in _PLANE_TALLIES.get():
+        tally["in_place" if in_place else "copied"] += 2 * n_planes * k8 * n
+    layer = None
+    if isinstance(pp, StackedPlanes):
+        if in_place:
+            layer, pp, pn = pp.index, pp.stack, pn.stack
+        else:
+            pp, pn = pp.stack[pp.index], pn.stack[pn.index]
     # a padded copy of the planes is made only where N or K is not a
     # multiple of its block (the heuristic's blocks avoid that wherever N
     # is a multiple of 128: the TPU compiler refuses a shorter copy)
-    ppp = ops._pad_to(ops._pad_to(pp, k_pad // 8, 1), bn, 2)
-    pnp = ops._pad_to(ops._pad_to(pn, k_pad // 8, 1), bn, 2)
+    ppp = ops._pad_to(ops._pad_to(pp, k_pad // 8, -2), bn, -1)
+    pnp = ops._pad_to(ops._pad_to(pn, k_pad // 8, -2), bn, -1)
     gp = ops._pad_to(gamma, bn, 0)
     zp = ops._pad_to(zcol, bn, 0)
     y = _pk.pann_matmul_packed_act(xp, ppp, pnp,
                                    _qparams(s, z, n_lvl, shift),
-                                   gp, zp, bm=bm, bn=bn, bk=bk,
+                                   gp, zp, layer, bm=bm, bn=bn, bk=bk,
                                    depth=params.depth,
                                    grid_order=params.order,
                                    interpret=interpret)
@@ -336,8 +394,8 @@ def _dispatch_rows(xf: Array, p: dict, s, z, n_lvl, gamma: Array,
     the backends then differ by an ulp, and greedy tokens drift apart."""
     w_q = p["w_q"]
     if name == "fused":
-        n_planes = (p["w_planes_pos"].shape[-3] if "w_planes_pos" in p
-                    else INT8_PLANES)
+        n_planes = (_plane_layer_shape(p["w_planes_pos"])[0]
+                    if "w_planes_pos" in p else INT8_PLANES)
         y = _matmul_fused(xf, w_q, s, z, n_lvl, gamma, zcol, n_planes,
                           interpret, shift=shift)
     elif name == "packed":
@@ -367,7 +425,8 @@ def _dispatch_ref(xf: Array, w_q: Array, s, z, n_lvl, gamma: Array,
 def serving_linear(x: Array, p: dict, backend: str) -> Array:
     """The serving projection: y = affine-quant(x) @ deq(w_q) [+ b] through
     the selected backend. ``p`` is one module's serving artifact (2-D w_q —
-    scan bodies slice stacked leaves before we ever see them).
+    callers slice stacked leaves before we ever see them; the plane leaves
+    may instead name their layer of the stack, ``StackedPlanes``).
 
     Output dtype follows x; the fp32 result is bit-identical across
     backends (module docstring). ``act_n`` (2^b~x - 1, a data leaf so
@@ -378,7 +437,7 @@ def serving_linear(x: Array, p: dict, backend: str) -> Array:
     w_q = p["w_q"]
     assert w_q.ndim == 2, (
         f"serving_linear wants a per-layer (K, N) weight slice, got "
-        f"{w_q.shape} — scan bodies must slice stacked leaves first")
+        f"{w_q.shape} — callers must slice stacked leaves first")
     lead, k = x.shape[:-1], x.shape[-1]
     n_out = w_q.shape[-1]
 

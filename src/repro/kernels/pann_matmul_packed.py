@@ -214,15 +214,19 @@ def pann_matmul_packed(x_q: Array, packed_pos: Array, packed_neg: Array,
 # Fused act-quant prologue + plane DMAs pipelined across grid steps
 # ---------------------------------------------------------------------------
 
-def _act_kernel(qp_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref, zcol_ref, o_ref,
-                xbuf, codes, pos_buf, neg_buf, w_ref, acc_ref, xsem, pos_sem,
-                neg_sem, *, grid: tuple[int, int, int], bk: int, depth: int,
+def _act_kernel(qp_ref, layer_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref,
+                zcol_ref, o_ref, xbuf, codes, pos_buf, neg_buf, w_ref,
+                acc_ref, xsem, pos_sem, neg_sem, *,
+                grid: tuple[int, int, int], bk: int, depth: int,
                 i_axis: int, j_axis: int, encode_every_step: bool):
     """Packed twin of ``pann_matmul._pann_matmul_act_kernel`` (see its
     docstring for the prologue): fp32 x is DMA'd + affine-encoded into a
     persistent VMEM codes panel on its first visit.
 
-    Planes: a grid step's (P, bk/8, bn) plane tiles land in one of
+    Planes: the store is (L, P, K/8, N) and the call reads layer
+    ``layer_ref[0]`` of it (an SMEM scalar, so every layer of a stack
+    shares this one kernel). A grid step's (P, bk/8, bn) plane tiles of
+    that layer land in one of
     ``depth`` VMEM slots, 128 lanes at a time, so that every (32, 128)
     piece of a plane is one contiguous vreg there. The copies for step
     t+depth-1 start before step t waits on its own, so the plane stream
@@ -243,6 +247,7 @@ def _act_kernel(qp_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref, zcol_ref, o_ref,
         shift = jnp.round(qp_ref[0, 3]).astype(jnp.int32)
     else:
         shift = jnp.int32(0)
+    layer = layer_ref[0]
     bm = xbuf.shape[0]
     bk8 = bk // 8
     n_chunks = pos_buf.shape[1]            # bn / 128
@@ -254,7 +259,7 @@ def _act_kernel(qp_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref, zcol_ref, o_ref,
         rows = pl.ds(pl.multiple_of((step % k_steps) * bk8, bk8), bk8)
         slot = step % depth
         return [pltpu.make_async_copy(
-                    hbm.at[:, rows, pl.ds(pl.multiple_of(
+                    hbm.at[layer, :, rows, pl.ds(pl.multiple_of(
                         (j_step * n_chunks + c) * _LANES, _LANES), _LANES)],
                     buf.at[slot, c], sem.at[slot])
                 for c in range(n_chunks)
@@ -326,16 +331,20 @@ def _act_kernel(qp_ref, x_hbm, pos_hbm, neg_hbm, gamma_ref, zcol_ref, o_ref,
                                              "grid_order", "interpret"))
 def pann_matmul_packed_act(x: Array, packed_pos: Array, packed_neg: Array,
                            qparams: Array, gamma: Array,
-                           zcol: Array | None = None, *, bm: int = 128,
-                           bn: int = 128, bk: int = 256, depth: int = 2,
+                           zcol: Array | None = None, layer=None, *,
+                           bm: int = 128, bn: int = 128, bk: int = 256,
+                           depth: int = 2,
                            grid_order: str = "mnk",
                            interpret: bool = True) -> Array:
     """Fused-prologue packed-plane matmul: quantize-in-kernel on the
     2*P/8-bytes-per-weight deployment artifact.
 
     x (M, K) f32 with its columns in ``permute_k`` order; packed_pos/neg
-    (P, K/8, N) uint8, P <= 7; K % bk == 0, bk % K_GROUP == 0, N % bn ==
-    0, bn % 128 == 0.
+    (L, P, K/8, N) uint8, a stack of L layers' planes, of which the call
+    reads ``layer`` (an int32 scalar, traced: every layer shares one
+    kernel) where it lies; (P, K/8, N) is the stack of one (L = 1, layer
+    0). P <= 7; K % bk == 0, bk % K_GROUP == 0, N % bn == 0, bn % 128 ==
+    0.
     qparams (1, 4) f32 SMEM scalars [s, z, n_lvl, plane_shift]
     (``quant.affine_scale_zp`` outside the kernel — the shared
     cross-backend derivation; plane_shift = LOW planes to skip at runtime,
@@ -344,8 +353,12 @@ def pann_matmul_packed_act(x: Array, packed_pos: Array, packed_neg: Array,
     accumulator. ``depth`` = grid steps whose planes are in VMEM at once
     (2: double-buffered); ``grid_order`` as in ``pann_matmul_act``.
     """
+    if packed_pos.ndim == 3:
+        packed_pos, packed_neg = packed_pos[None], packed_neg[None]
+    layer = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
     m, k = x.shape
-    p, k8, n = packed_pos.shape
+    _, p, k8, n = packed_pos.shape
+    assert packed_neg.shape == packed_pos.shape
     assert k8 * 8 == k and bk % K_GROUP == 0 and p <= 7
     assert m % bm == 0 and n % bn == 0 and k % bk == 0 and bn % _LANES == 0
     assert qparams.shape in ((1, 3), (1, 4)), qparams.shape
@@ -374,6 +387,7 @@ def pann_matmul_packed_act(x: Array, packed_pos: Array, packed_neg: Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),       # qparams
+            pl.BlockSpec(memory_space=pltpu.SMEM),       # layer
             pl.BlockSpec(memory_space=pl.ANY),        # x (manual DMA)
             pl.BlockSpec(memory_space=pl.ANY),        # packed_pos
             pl.BlockSpec(memory_space=pl.ANY),        # packed_neg
@@ -396,5 +410,5 @@ def pann_matmul_packed_act(x: Array, packed_pos: Array, packed_neg: Array,
             pltpu.SemaphoreType.DMA((depth,)),
         ],
         interpret=interpret,
-    )(qparams, x, packed_pos, packed_neg, gamma.reshape(1, -1),
+    )(qparams, layer, x, packed_pos, packed_neg, gamma.reshape(1, -1),
       zcol.reshape(1, -1))

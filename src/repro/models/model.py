@@ -2,8 +2,9 @@
 
 Layers are stacked per repeating group and scanned (lax.scan) with optional
 per-group rematerialization; heterogeneous tails (e.g. zamba2's 38 = 6x6+2)
-run unrolled after the scan. Decode threads stacked per-group caches through
-the same scan.
+run unrolled after the scan. Decode runs the groups as straight-line code,
+slicing each group's caches and params out of the stacks, the packed planes
+excepted (the kernel reads them in the store).
 """
 from __future__ import annotations
 
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import calibrate as CAL
 from repro.dist import constrain as C
+from repro.kernels import dispatch
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import transformer as T
@@ -344,6 +346,27 @@ def init_decode_state(params: dict, cfg: ModelConfig, batch: int,
                        position=jnp.zeros((), jnp.int32))
 
 
+def _take(tree, g: int):
+    """Every leaf of ``tree`` at index ``g`` of its leading (group) axis."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.index_in_dim(a, g, keepdims=False), tree)
+
+
+def _group_params(groups: dict, g: int) -> dict:
+    """Group ``g``'s params out of the stacked groups: every leaf sliced at
+    ``g``, except the packed planes of a module that holds one matrix a
+    group, (G, P, K/8, N). Those stay in the store as
+    ``dispatch.StackedPlanes(stack, g)``, which the packed kernel reads in
+    place: a slice of a leaf that feeds a kernel is a copy of it."""
+    def one(path, a):
+        if getattr(path[-1], "key", None) in dispatch.PLANE_KEYS \
+                and a.ndim == 4:
+            return dispatch.StackedPlanes(a, g)
+        return jax.lax.index_in_dim(a, g, keepdims=False)
+
+    return jax.tree_util.tree_map_with_path(one, groups)
+
+
 def decode_step(params: dict, cfg: ModelConfig, state: DecodeState,
                 tokens: Array) -> tuple[Array, DecodeState]:
     """tokens: (B, 1) -> (logits (B, 1, V), new state)."""
@@ -352,8 +375,7 @@ def decode_step(params: dict, cfg: ModelConfig, state: DecodeState,
     shared = params.get("shared_attn")
     x = _embed(params, cfg, tokens, dtype)
 
-    def group_body(h, xs):
-        gp, gcache, gcross = xs
+    def group_body(h, gp, gcache, gcross):
         new_caches = []
         xi = 0
         for i, spec in enumerate(pattern):
@@ -366,14 +388,24 @@ def decode_step(params: dict, cfg: ModelConfig, state: DecodeState,
             new_caches.append(c)
         return h, tuple(new_caches)
 
-    xs = (params["decoder"]["groups"], state.caches["groups"], state.cross_kv)
-    n_groups = jax.tree_util.tree_leaves(xs[0])[0].shape[0]
-    # ALWAYS unrolled for decode: a while-loop over groups makes GSPMD
-    # all-gather the stacked KV caches as loop xs (measured 7.1e10 B/device
-    # per step — the entire global cache; §Perf iteration 4b). Decode bodies
-    # are single-token, so straight-line code is cheap to compile and keeps
-    # every layer's cache shard-local.
-    x, new_group_caches = jax.lax.scan(group_body, x, xs, unroll=n_groups)
+    groups = params["decoder"]["groups"]
+    n_groups = jax.tree_util.tree_leaves(groups)[0].shape[0]
+    # ALWAYS unrolled for decode, a Python loop over the groups: a
+    # while-loop over groups makes GSPMD all-gather the stacked KV caches
+    # as loop xs (measured 7.1e10 B/device per step — the entire global
+    # cache; §Perf iteration 4b). Decode bodies are single-token, so
+    # straight-line code is cheap to compile and keeps every layer's cache
+    # shard-local.
+    outs = []
+    for g in range(n_groups):
+        gcross = (None if state.cross_kv is None
+                  else _take(state.cross_kv, g))
+        x, c = group_body(x, _group_params(groups, g),
+                          _take(state.caches["groups"], g), gcross)
+        outs.append(c)
+    new_group_caches = (jax.tree_util.tree_map(lambda *a: jnp.stack(a),
+                                               *outs)
+                        if outs else state.caches["groups"])
 
     new_caches = {"groups": new_group_caches}
     if "tail" in state.caches:

@@ -22,7 +22,9 @@ jitted step ``decode_step`` (module ``jit_decode_step``) and scopes inside it
 (``ssm_state``, ``act_range``, ``rwkv_shift``, ``rwkv_wkv``). The counters
 ``decode_steps``, ``prefill_steps`` and ``live_rows`` are reported by
 ``describe()``, with ``recurrent_state_bytes``, the recurrent state one lane
-holds.
+holds, and ``plane_bytes_in_place`` / ``plane_bytes_copied``, the packed
+planes one decode step reads from the weight store where they lie and
+through a sliced or padded copy (None until the step is traced).
 """
 from __future__ import annotations
 
@@ -236,8 +238,17 @@ class ServeEngine:
             self._autotune_projections()
         self._frontend_kwargs_fn = frontend_kwargs_fn
 
+        # the packed planes one step reads, in place and through copies:
+        # tallied from shapes each time the step is traced
+        self.plane_bytes_in_place: Optional[int] = None
+        self.plane_bytes_copied: Optional[int] = None
+
         def decode_step(p, s, t):
-            return MD.decode_step(p, cfg, s, t)
+            with dispatch.plane_traffic() as tally:
+                out = MD.decode_step(p, cfg, s, t)
+            self.plane_bytes_in_place = tally["in_place"]
+            self.plane_bytes_copied = tally["copied"]
+            return out
 
         # a named function, so the trace names its module jit_decode_step
         self._step = jax.jit(decode_step)
@@ -621,4 +632,6 @@ class ServeEngine:
             "prefill_steps": self.prefill_steps,
             "live_rows": self.live_rows,
             "recurrent_state_bytes": self.recurrent_state_bytes,
+            "plane_bytes_in_place": self.plane_bytes_in_place,
+            "plane_bytes_copied": self.plane_bytes_copied,
         }

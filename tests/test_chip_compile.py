@@ -12,6 +12,7 @@ process at a time may load the TPU library, and test workers import every
 test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -161,3 +162,56 @@ def test_ref_projection_leaves_through_its_barrier(spec):
     assert any(" convolution(" in c for c in comps)
     assert not [c.split("\n")[0] for c in comps
                 if " convolution(" in c and " reduce(" in c]
+
+
+# an HLO instruction: its result type and its opcode
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z-]+)\(")
+_COPYING = {"copy", "copy-start", "copy-done", "fusion", "slice",
+            "dynamic-slice"}
+
+
+def _plane_copies(text: str, shapes) -> list[str]:
+    """Instructions of ``text`` that make a value of one of the uint8
+    plane ``shapes`` (``K/8,N``), with or without a leading stack dim of
+    1, by a copy, a slice or a fusion."""
+    pat = re.compile(r"u8\[(?:1,)?7,(?:%s)\]" % "|".join(shapes))
+    out = []
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(2) in _COPYING and pat.search(m.group(1)):
+            out.append(line.strip()[:160])
+    return out
+
+
+def test_packed_matmul_reads_stacked_planes_in_place(spec, monkeypatch):
+    """Finch's channel mix, wk (2048 -> 7168) and wv (7168 -> 2048), on a
+    3-layer stacked store, each layer through ``model._group_params`` and
+    ``serving_linear``: the stacks are the kernel's operands themselves,
+    and no layer's planes are copied out of them first."""
+    from repro.models import model as MD
+    layers, rows = 3, 16
+    # compiled for the described chip: the kernel, not its interpreter
+    monkeypatch.setattr(dispatch.ops, "on_tpu", lambda: True)
+
+    def store(k, n):
+        planes = spec((layers, PLANES, k // 8, n), jnp.uint8)
+        return {"w_q": spec((layers, k, n), jnp.int8),
+                "w_scale": spec((layers, 1, n), jnp.float32),
+                "w_colsum": spec((layers, n), jnp.int32),
+                "act_nlvl": spec((layers,), jnp.float32),
+                "plane_shift": spec((layers,), jnp.float32),
+                "w_planes_pos": planes, "w_planes_neg": planes}
+
+    def f(groups, x):
+        for g in range(layers):
+            gp = MD._group_params(groups, g)
+            h = jax.nn.relu(dispatch.serving_linear(x, gp["wk"], "packed"))
+            x = dispatch.serving_linear(h, gp["wv"], "packed")
+        return x
+
+    groups = {"wk": store(2048, 7168), "wv": store(7168, 2048)}
+    text = _compiled_text(f, groups, spec((rows, 2048), jnp.float32))
+    assert text.count("tpu_custom_call") >= 2 * layers
+    assert "u8[3,7,256,7168]" in text and "u8[3,7,896,2048]" in text
+    copies = _plane_copies(text, ("256,7168", "896,2048"))
+    assert not copies, copies

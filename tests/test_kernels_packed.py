@@ -6,7 +6,7 @@ import pytest
 
 from repro.core import pann as pann_core
 from repro.core.unsigned import unsigned_split
-from repro.kernels import dispatch, ref
+from repro.kernels import autotune, dispatch, ref
 from repro.kernels.pann_matmul_packed import (pack_planes, pann_matmul_packed,
                                               permute_k, swar_tile,
                                               unpack_planes)
@@ -169,3 +169,48 @@ def test_packed_dispatch_is_bit_exact_at_serving_widths(m, k, n):
     """Decode row counts at zamba2 widths, and a K that is not a multiple
     of 256 with an N that no 512-lane block divides."""
     _dispatch_case(m, k, n, "random", 1)
+
+
+# ---------------------------------------------------------------------------
+# a layer of a stacked (L, P, K/8, N) store, read where it lies
+# ---------------------------------------------------------------------------
+
+SMALL_BLOCKS = autotune.KernelParams(bm=32, bn=128, bk=256, depth=2,
+                                     order="mnk")
+
+
+@pytest.mark.parametrize("k,n,layer,shift,params,where", [
+    (512, 384, 0, 0, None, "in_place"),
+    (512, 384, 1, 3, None, "in_place"),
+    (512, 384, 2, 0, SMALL_BLOCKS, "in_place"),   # 3 x 2 grid steps
+    (512, 384, 2, 5, SMALL_BLOCKS._replace(order="nmk"),
+     "in_place"),
+    (32, 160, 1, 0, None, "copied"),              # K and N padded
+    (32, 160, 2, 3, None, "copied"),
+])
+def test_packed_dispatch_reads_a_stacked_layer(k, n, layer, shift, params,
+                                               where):
+    """The call on layer ``layer`` of a 3-layer stack equals the call on
+    that layer's sliced leaf bit for bit, at plane shift 0 and a rung's;
+    it reads the stack in place where K and N fill whole blocks, and
+    through a sliced, padded copy elsewhere."""
+    leaves = [_packed_leaves(_codes("random", k, n)) for _ in range(3)]
+    pp = jnp.stack([a for a, _ in leaves])
+    pn = jnp.stack([b for _, b in leaves])
+    xf = jnp.asarray(RNG.standard_normal((4, k)), jnp.float32)
+    s, z, n_lvl = jnp.float32(0.03), jnp.float32(60.0), jnp.float32(127.0)
+    gamma = jnp.asarray(RNG.uniform(0.001, 0.01, (n,)), jnp.float32)
+    zcol = jnp.asarray(RNG.integers(-1000, 1000, (n,)), jnp.int32)
+    sh = jnp.float32(shift)
+
+    def call(a, b):
+        return dispatch._matmul_packed(xf, a, b, s, z, n_lvl, gamma, zcol,
+                                       interpret=True, shift=sh,
+                                       params=params)
+
+    with dispatch.plane_traffic() as tally:
+        got = call(dispatch.StackedPlanes(pp, layer),
+                   dispatch.StackedPlanes(pn, layer))
+    want = call(pp[layer], pn[layer])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert tally == {"in_place": 0, "copied": 0, where: 2 * pp[0].size}

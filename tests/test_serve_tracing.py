@@ -1,7 +1,9 @@
 """The engine's own instrumentation: ``serve.*`` host spans in a profiler
-trace, the step counters ``describe()`` reports, and the device names of
+trace, the counters ``describe()`` reports (steps, and the packed plane
+bytes a step reads in place and through copies), and the device names of
 the decode step (module ``jit_decode_step``, scopes ``ssm_state`` and
-``act_range``). Reduced zamba2 on CPU."""
+``act_range``). Reduced zamba2 (and RWKV-6) on CPU."""
+import dataclasses
 import glob
 import os
 
@@ -13,6 +15,7 @@ from jax.profiler import ProfileData
 
 from repro import configs
 from repro.models import model as MD
+from repro.models import transformer as T
 from repro.serve_engine import (Request, ServeEngine, build_ladder,
                                 select_rung)
 from repro.serve_engine.scheduler import Scheduler
@@ -171,3 +174,46 @@ def test_decode_step_names_its_module_and_scopes(setup):
     assert "module @jit_decode_step" in text
     assert "ssm_state" in text
     assert "act_range" in text
+
+
+def _plane_bytes(variant, cfg) -> dict:
+    """The packed planes one decode step reads, from the store's leaves:
+    each module's pos and neg planes once a step (the zamba2 shared block
+    once a use), split by whether a layer's planes fill whole blocks (K a
+    multiple of the kernel's 256-row groups, N of its 128 lanes) or have
+    to be padded."""
+    pattern, n_groups, n_tail = T.group_layout(cfg)
+    kinds = [s.kind for s in pattern] * n_groups + \
+        [pattern[i % len(pattern)].kind for i in range(n_tail)]
+    out = {"in_place": 0, "copied": 0}
+    for path, a in jax.tree_util.tree_leaves_with_path(variant):
+        if getattr(path[-1], "key", None) not in ("w_planes_pos",
+                                                  "w_planes_neg"):
+            continue
+        _, k8, n = a.shape[-3:]
+        uses = (kinds.count("mamba_attn")
+                if path[0].key == "shared_attn" else 1)
+        padded = (k8 * 8) % 256 or n % 128
+        out["copied" if padded else "in_place"] += a.size * uses
+    return out
+
+
+@pytest.mark.parametrize("arch", ["rwkv6-1.6b", "zamba2-1.2b"])
+def test_engine_counts_the_plane_bytes_of_a_step(arch):
+    """At width 256 some modules fill the packed kernel's blocks and some
+    (the LoRAs, the Mamba2 in-projection) do not: ``describe()`` counts the
+    first read in place from the stacked store, the padded ones copied,
+    and the two add up to the planes of one step."""
+    cfg = dataclasses.replace(configs.reduced(configs.get_config(arch)),
+                              d_model=256, d_ff=512)
+    eng = ServeEngine(cfg, MD.init_params(jax.random.PRNGKey(0), cfg),
+                      ladder_bits=(4,), max_batch=MAX_BATCH, max_len=8,
+                      backend="packed:force")
+    assert eng.describe()["plane_bytes_in_place"] is None
+    eng._step.trace(eng.variants[4], eng._init_state(4),
+                    jnp.zeros((MAX_BATCH, 1), jnp.int32))
+    got = eng.describe()
+    want = _plane_bytes(eng.variants[4], cfg)
+    assert want["in_place"] and want["copied"]
+    assert (got["plane_bytes_in_place"], got["plane_bytes_copied"]) == \
+        (want["in_place"], want["copied"])
