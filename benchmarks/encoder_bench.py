@@ -65,16 +65,14 @@ def _conv_parity(cfg, params, raw) -> dict:
     spec0 = cfg.conv_stem[0]
     for backend in BACKENDS:
         sp = serving.quantize_params_for_serving(
-            params, cfg, spec=serving.ServingQuantSpec(
-                r=4.0, act_bits=6,
-                pack_planes=backend.startswith("packed")))
+            params, cfg, r=4.0, act_bits=6,
+            pack_planes=dispatch.reads_planes(backend))
         p = sp["conv_stem"]["s0"]
         y = dispatch.serving_conv(raw, p, spec0, backend)
         out[f"backend:{backend}"] = _exact(
             y, dispatch.serving_conv_oracle(raw, p, spec0))
     ws = serving.build_weight_store(
-        params, cfg, {2: (2.0, 6), 6: (16.0, 6)},
-        spec=serving.ServingQuantSpec(pack_planes=True))
+        params, cfg, {2: (2.0, 6), 6: (16.0, 6)}, pack_planes=True)
     for rung, view in ws.views.items():
         p = view["conv_stem"]["s0"]
         y = dispatch.serving_conv(raw, p, spec0, "packed:force")

@@ -103,8 +103,8 @@ class ModelConfig:
     # --- serving ---
     kv_cache_dtype: str = ""      # "" = compute dtype; "float8_e4m3fn" halves
     #                               KV-cache bytes for decode (§Perf iter. 7)
-    # Serving-matmul backend for quantized (w_q) projections — None (legacy
-    # float dequant) | "ref" | "fused" | "packed" (repro.kernels.dispatch).
+    # Serving-matmul backend for quantized (w_q) projections — "ref" |
+    # "fused" | "packed" (repro.kernels.dispatch); None reads as "ref".
     # Trace-time static: one jitted decode step per backend.
     kernel_backend: Optional[str] = None
     # Quantized KV cache: when set, decode stores K/V as packed bit-plane

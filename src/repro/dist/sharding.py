@@ -190,7 +190,7 @@ def to_named(specs: Any, mesh) -> Any:
 
 
 # ---------------------------------------------------------------------------
-# Fleet: rung shards (host-level sharding of the ladder's variant cache)
+# Fleet: rung shards (host-level sharding of the ladder's rung views)
 # ---------------------------------------------------------------------------
 
 def rung_shard(ladder_bits: Sequence[int], n_hosts: int

@@ -1,9 +1,10 @@
 """Pluggable backends for the serving matmul — the single choke point that
 turns the PANN deployment artifact ({"w_q", "w_scale", ...}; built by
-``models/serving.quantize_params_for_serving``) into projection outputs.
+``models/serving.build_weight_store``) into projection outputs, and the one
+module that knows what each backend needs.
 
 Backends (selected per engine via ``ModelConfig.kernel_backend`` and threaded
-through ``models.layers.apply_linear``):
+through ``models.layers.apply_linear``; None reads as 'ref'):
 
   ``ref``     plain-jnp integer dataflow — runs on any platform; the oracle.
   ``fused``   Pallas bit-plane matmul (``kernels/pann_matmul``, mode='fused'):
@@ -114,6 +115,12 @@ def effective_backend(spec: str) -> str:
     return f"{name}:interpret" if force else "ref"
 
 
+def reads_planes(spec: str) -> bool:
+    """Does backend ``spec`` read the packed plane leaves? Only 'packed'
+    does, so only its artifacts are built with ``pack_planes``."""
+    return parse_backend(spec)[0] == "packed"
+
+
 def resolve_backend(spec: str, p: dict) -> tuple[str, bool]:
     """(effective backend, interpret flag) for artifact ``p`` on this host.
 
@@ -125,11 +132,11 @@ def resolve_backend(spec: str, p: dict) -> tuple[str, bool]:
     name, force = parse_backend(spec)
     if name == "ref":
         return "ref", False
-    if name == "packed" and "w_planes_pos" not in p:
+    if reads_planes(spec) and "w_planes_pos" not in p:
         raise ValueError(
             "backend 'packed' needs the w_planes_pos/w_planes_neg artifact "
-            "leaves; build the variant with "
-            "quantize_params_for_serving(..., pack_planes=True)")
+            "leaves; build the store with "
+            "build_weight_store(..., pack_planes=True)")
     if not ops.on_tpu() and not force:
         return "ref", False
     return name, not ops.on_tpu()
@@ -422,18 +429,19 @@ def _dispatch_ref(xf: Array, w_q: Array, s, z, n_lvl, gamma: Array,
     return _matmul_ref(q8, w_ref_q, s, gamma, zcol)
 
 
-def serving_linear(x: Array, p: dict, backend: str) -> Array:
+def serving_linear(x: Array, p: dict, backend: str | None) -> Array:
     """The serving projection: y = affine-quant(x) @ deq(w_q) [+ b] through
-    the selected backend. ``p`` is one module's serving artifact (2-D w_q —
-    callers slice stacked leaves before we ever see them; the plane leaves
-    may instead name their layer of the stack, ``StackedPlanes``).
+    the selected backend (None reads as 'ref'). ``p`` is one module's
+    serving artifact (2-D w_q — callers slice stacked leaves before we ever
+    see them; the plane leaves may instead name their layer of the stack,
+    ``StackedPlanes``).
 
     Output dtype follows x; the fp32 result is bit-identical across
     backends (module docstring). ``act_n`` (2^b~x - 1, a data leaf so
     ladder rungs share one compilation) sets the activation levels; absent,
     activations quantize at the 8-bit operating point's half-range.
     """
-    name, interpret = resolve_backend(backend, p)
+    name, interpret = resolve_backend(backend or "ref", p)
     w_q = p["w_q"]
     assert w_q.ndim == 2, (
         f"serving_linear wants a per-layer (K, N) weight slice, got "
@@ -461,7 +469,7 @@ def serving_linear(x: Array, p: dict, backend: str) -> Array:
     return y.reshape(*lead, n_out).astype(x.dtype)
 
 
-def serving_conv(x: Array, p: dict, spec, backend: str) -> Array:
+def serving_conv(x: Array, p: dict, spec, backend: str | None) -> Array:
     """The serving CONV projection: im2col over the serving matmuls.
 
     ``x``: (B, H, W, Cin) fp input; ``p``: the layer's serving artifact with
@@ -479,8 +487,9 @@ def serving_conv(x: Array, p: dict, spec, backend: str) -> Array:
     bit-exactness contract is unaffected. Padding happens in fp BEFORE the
     encode: with include_zero ranges the border encodes to exactly z and
     the zcol correction makes it an exact no-op (pann_conv docstring).
+    ``backend`` None reads as 'ref', as in ``serving_linear``.
     """
-    name, interpret = resolve_backend(backend, p)
+    name, interpret = resolve_backend(backend or "ref", p)
     w_q = p["w_q"]
     assert w_q.ndim == 2 and x.ndim == 4, (w_q.shape, x.shape)
     b = x.shape[0]

@@ -15,8 +15,8 @@ to fp32 tolerance — the train→serve loop closes on numbers, not vibes.
 
 The ``--out`` artifact directory uses the checkpoint layout (arrays.npz +
 meta.json, atomic COMMITTED marker) so ``ckpt.checkpoint.restore`` loads it
-straight into a serving tree; ``examples/serve_lm.py`` / the serve engine
-consume it via ``build_variant_cache``-shaped params.
+straight into a serving tree: one operating point's artifact, as
+``models.serving.quantize_params_for_serving`` builds it.
 
 ``--artifact_out`` additionally writes the mmap-able LADDER artifact
 (``serve_engine.artifact``: manifest.json + weights.bin): one max-budget
@@ -123,16 +123,15 @@ def main(argv=None) -> dict:
 
         calib = state.calib if qat else None
         if tree is not None:
-            qspec = serving.ServingQuantSpec(policy=tree, calib=calib)
+            point = dict(policy=tree)
         else:
-            qspec = serving.ServingQuantSpec(r=float(uniform_pt[0]),
-                                             act_bits=int(uniform_pt[1]),
-                                             calib=calib)
-        variant = serving.quantize_params_for_serving(state.params, cfg,
-                                                      spec=qspec)
+            point = dict(r=float(uniform_pt[0]), act_bits=int(uniform_pt[1]))
+        variant = serving.quantize_params_for_serving(
+            state.params, cfg, calib=calib, **point)
 
-        # the exported artifact through the SERVING forward (w_q dequant +
-        # frozen static activation ranges) on the same held-out batch
+        # the exported artifact through the SERVING forward (the integer
+        # dispatch path + frozen static activation ranges) on the same
+        # held-out batch
         loss_serve = ST.eval_loss(variant, cfg_eval, batch)
 
     abs_diff = abs(loss_serve - loss_train)
@@ -168,9 +167,8 @@ def main(argv=None) -> dict:
             specs = {0: (float(uniform_pt[0]),
                          None if uniform_pt[1] is None
                          else int(uniform_pt[1]))}
-        ws = serving.build_weight_store(
-            state.params, cfg, specs,
-            spec=serving.ServingQuantSpec(pack_planes=True, calib=calib))
+        ws = serving.build_weight_store(state.params, cfg, specs,
+                                        pack_planes=True, calib=calib)
         summary["artifact_out"] = artifact.write_artifact(
             args.artifact_out, ws,
             meta={"source_ckpt": args.ckpt_dir, "step": step,

@@ -2,7 +2,8 @@
 optional PANN-quantized weights (the deployment story of the paper: pick a
 power budget, plan (b~x, R) with Algorithm 1, serve).
 
-Single operating point (legacy path):
+Single operating point (with --quant pann, the PANN serving artifact through
+--backend):
 
     PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-1.6b --reduced \
         --batch 4 --prompt_len 32 --gen 16 --quant pann --power_bits 4
@@ -37,6 +38,7 @@ from repro import configs
 from repro.configs.base import QuantConfig
 from repro.core import costs, planner
 from repro.data.pipeline import frontend_raw_stub, frontend_stub
+from repro.kernels import dispatch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as MD
 from repro.models import serving
@@ -78,7 +80,7 @@ def serve_fleet(args) -> dict:
         cap_gbitflips_per_s=args.global_budget,
         max_batch=args.batch,
         max_len=args.prompt_len + args.gen + 2,
-        backend=args.backend or None,
+        backend=args.backend,
     )
     spec = TrafficSpec(seed=args.seed + 7, n_ticks=args.ticks,
                        prompt_lens=(args.prompt_len,),
@@ -128,7 +130,7 @@ def serve_encode(args) -> dict:
     engine = EncodeEngine(cfg, params, ladder_bits=ladder_bits,
                           max_batch=args.batch,
                           allocation=args.allocation,
-                          backend=args.backend or None)
+                          backend=args.backend)
     engine.warmup()
     total_macs = sum(m.macs for m in engine.profile)
     for op in engine.ladder:
@@ -191,10 +193,9 @@ def serve_ladder(args) -> dict:
     engine = ServeEngine(cfg, params, ladder_bits=ladder_bits,
                          max_batch=args.batch, max_len=max_len,
                          allocation=args.allocation,
-                         backend=args.backend or None,
+                         backend=args.backend,
                          autotune=args.autotune,
                          cache_bits=cache_bits,
-                         artifact_format=args.artifact_format,
                          frontend_kwargs_fn=fe_fn)
     t0 = time.monotonic()
     engine.warmup()
@@ -261,17 +262,18 @@ def main(argv=None) -> dict:
                          "rung, or a per-module PolicyTree spending the "
                          "same total power layer-wise "
                          "(planner.allocate_layerwise)")
-    ap.add_argument("--backend", default="",
-                    choices=["", "ref", "fused", "packed", "fused:force",
+    ap.add_argument("--backend", default="packed",
+                    choices=["ref", "fused", "packed", "fused:force",
                              "packed:force"],
                     help="serving-matmul backend (repro.kernels.dispatch): "
                          "ref (jnp integer oracle), fused (Pallas bit-plane "
                          "MXU matmul), packed (bit-packed planes, 8 "
-                         "codes/byte along K); ':force' runs Pallas in "
-                         "interpret mode off-TPU. Empty = legacy float "
-                         "dequant. With --quant pann (no ladder) the "
+                         "codes/byte along K; the default, which runs as "
+                         "ref off TPU); ':force' runs Pallas in interpret "
+                         "mode off-TPU. With --quant pann (no ladder) the "
                          "weights are materialized as the serving artifact "
-                         "and decode runs through the chosen backend.")
+                         "and decode runs through the chosen backend; other "
+                         "--quant modes serve the training-form model.")
     ap.add_argument("--autotune", action="store_true",
                     help="measure-and-cache the best Pallas block shapes "
                          "per projection before warmup (kernels/autotune; "
@@ -288,14 +290,6 @@ def main(argv=None) -> dict:
                          "packed bit-plane cache directly "
                          "(kernels/pann_attention via --backend, jnp ref "
                          "oracle otherwise). Empty = fp cache.")
-    ap.add_argument("--artifact_format", default="views",
-                    help="ladder materialization (DESIGN.md §11): 'views' "
-                         "(the only format) quantizes once at the "
-                         "per-module max budget and serves every rung as a "
-                         "zero-copy view over one weight store (HBM flat "
-                         "in ladder depth; rung budgets snapped to powers "
-                         "of two). The per-rung 'legacy' format was "
-                         "retired.")
     ap.add_argument("--encode", action="store_true",
                     help="serve the ENCODER workload (vision/speech "
                          "frontends) instead of decode: whole-sequence "
@@ -323,16 +317,6 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
     enable_compile_cache()
 
-    if args.artifact_format == "legacy":
-        raise SystemExit(
-            "--artifact_format legacy was retired: the ladder is always "
-            "materialized as one weight store with zero-copy rung views "
-            "(DESIGN.md §11). Budget-snapping drift is bounded in closed "
-            "form by benchmarks/artifact_parity.py; drop the flag.")
-    if args.artifact_format != "views":
-        raise SystemExit(
-            f"unknown --artifact_format {args.artifact_format!r}; "
-            "the only format is 'views'")
     if args.encode:
         return serve_encode(args)
     if args.fleet_hosts:
@@ -348,7 +332,7 @@ def main(argv=None) -> dict:
     if args.cache_bits:
         raise SystemExit(
             "--cache_bits requires --power_ladder (the quantized KV cache "
-            "rides in the serve-engine variant cache)")
+            "rides in the serve-engine weight store)")
     cfg = configs.get_config(args.arch)
     if args.reduced:
         cfg = configs.reduced(cfg)
@@ -357,19 +341,15 @@ def main(argv=None) -> dict:
     cfg = dataclasses.replace(cfg, quant=qc)
 
     params = MD.init_params(jax.random.PRNGKey(args.seed), cfg)
-    if args.backend:
-        # route the single operating point through a kernel backend: weights
-        # become the serving artifact (int8 codes; packed plane leaves for
-        # 'packed' at the module's value-exact b_R) and every projection in
-        # the decode loop below dispatches through repro.kernels.dispatch
-        if args.quant != "pann":
-            raise SystemExit("--backend serves the PANN deployment artifact;"
-                             " combine it with --quant pann (or use "
-                             "--power_ladder)")
+    artifact = args.quant == "pann"
+    if artifact:
+        # the single operating point as the serving artifact (int8 codes;
+        # packed plane leaves for 'packed' at the module's value-exact b_R):
+        # every projection in the decode loop below dispatches through
+        # repro.kernels.dispatch
         params = serving.quantize_params_for_serving(
-            params, cfg, spec=serving.ServingQuantSpec(
-                r=qc.r, act_bits=qc.act_bits_tilde,
-                pack_planes=args.backend.startswith("packed")))
+            params, cfg, r=qc.r, act_bits=qc.act_bits_tilde,
+            pack_planes=dispatch.reads_planes(args.backend))
         cfg = dataclasses.replace(cfg, kernel_backend=args.backend)
     rng = np.random.default_rng(args.seed)
     prompts = jnp.asarray(
@@ -392,7 +372,7 @@ def main(argv=None) -> dict:
     for i in range(args.prompt_len):
         logits, state = step(params, state, prompts[:, i:i + 1])
     jax.block_until_ready(logits)
-    t_prefill = time.monotonic() - t0
+    prefill_time = time.monotonic() - t0
 
     # greedy decode
     t0 = time.monotonic()
@@ -410,10 +390,10 @@ def main(argv=None) -> dict:
     summary = {
         "arch": cfg.name,
         "quant": qc.mode,
-        "backend": args.backend or "legacy",
+        "backend": args.backend if artifact else None,
         "batch": args.batch,
         "generated": int(gen.shape[1]),
-        "prefill_s": round(t_prefill, 3),
+        "prefill_s": round(prefill_time, 3),
         "decode_s": round(t_decode, 3),
         "tok_per_s": round(args.batch * (args.gen - 1) / max(t_decode, 1e-9),
                            1),

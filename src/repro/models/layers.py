@@ -79,17 +79,6 @@ def affine_fake_quant(x: Array, bits: int) -> Array:
     return x + jax.lax.stop_gradient(xq - x)
 
 
-def affine_fake_quant_n(x: Array, n: Array) -> Array:
-    """``affine_fake_quant`` with a *traced* level count n = 2^b - 1.
-
-    Serving variants carry n as a data leaf (models/serving.py), so ladder
-    rungs with different b~x share one jit compilation — the whole point of
-    the serve_engine's recompilation-free traversal."""
-    xf = x.astype(jnp.float32)
-    q, s, z = quant.affine_quant_levels(xf, n)
-    return (s * (q - z)).astype(x.dtype)
-
-
 def affine_fake_quant_ranged(x: Array, bits: int, rng: Array) -> Array:
     """``affine_fake_quant`` against a calibrated [lo, hi] range (STE).
 
@@ -275,36 +264,17 @@ def init_linear(key, d_in: int, d_out: int, bias: bool = False,
 
 def apply_linear(x: Array, p: dict, qc, backend: Optional[str] = None,
                  path: Optional[str] = None) -> Array:
-    """The projection entry point. Training params route through ``qlinear``;
-    a serving artifact ("w_q" present) routes through the selected kernel
-    backend (``kernels.dispatch``: 'ref' | 'fused' | 'packed' — call sites
-    thread ``cfg.kernel_backend``), or through the legacy float dequant
-    below when ``backend`` is None (the pre-dispatch behavior, bit-exact).
+    """The projection entry point. A serving artifact ("w_q" present) routes
+    through the selected kernel backend (``kernels.dispatch``: 'ref' |
+    'fused' | 'packed', None reading as 'ref' — call sites thread
+    ``cfg.kernel_backend``); training params route through ``qlinear``.
     ``path`` identifies the module for activation-range calibration
     (``calib_tap``); inert unless a tap is installed.
     """
+    if "w_q" in p:
+        return dispatch.serving_linear(x, p, backend)
     b = p.get("b")
     b = None if b is None else b.astype(x.dtype)
-    if "w_q" in p:
-        if backend is not None:
-            return dispatch.serving_linear(x, p, backend)
-        # legacy serving path (models/serving.py): PANN int codes +
-        # per-channel gamma, dequantized on load — weight-read bytes are the
-        # int8 codes. "act_n" (= 2^b~x - 1, a data leaf so rungs share one
-        # compilation) quantizes activations at the operating point's b~x;
-        # "act_lo"/"act_hi" (export-frozen EMA calibration, launch/export.py)
-        # pin the range statically so serving reproduces calibrated QAT.
-        w = (p["w_q"].astype(jnp.float32)
-             * p["w_scale"]).astype(x.dtype)
-        if "act_lo" in p:
-            xf = x.astype(jnp.float32)
-            q, s, z = quant.affine_from_range(xf, p["act_n"],
-                                              p["act_lo"], p["act_hi"])
-            x = (s * (q - z)).astype(x.dtype)
-        elif "act_n" in p:
-            x = affine_fake_quant_n(x, p["act_n"])
-        y = x @ w
-        return y if b is None else y + b
     return qlinear(x, p["w"].astype(x.dtype), b, qc, path=path)
 
 
@@ -328,21 +298,19 @@ def init_conv(key, spec) -> dict:
 def apply_conv(x: Array, p: dict, cfg, spec, path: str) -> Array:
     """Conv projection through the same choke point as every linear.
 
-    x: (B, H, W, C) raw frontend input. Serving artifacts ("w_q" + a kernel
-    backend) route through ``dispatch.serving_conv`` — im2col over the fused
-    /packed integer matmuls, bit-identical to the int32 conv oracle. The
-    training / float path lowers to the *same* im2col (pad -> patches ->
-    matmul) and reuses ``apply_linear``: QAT fake-quant, calibration taps,
-    and the legacy dequant path all apply to conv exactly as to linears.
+    x: (B, H, W, C) raw frontend input. Serving artifacts ("w_q") route
+    through ``dispatch.serving_conv`` — im2col over the integer matmuls,
+    bit-identical to the int32 conv oracle. The training path lowers to the
+    *same* im2col (pad -> patches -> matmul) and reuses ``apply_linear``:
+    QAT fake-quant and calibration taps apply to conv exactly as to linears.
     """
-    if "w_q" in p and cfg.kernel_backend is not None:
+    if "w_q" in p:
         return dispatch.serving_conv(x, p, spec, cfg.kernel_backend)
     xpad = _pc.pad_nhwc(x.astype(jnp.float32), spec.ph, spec.pw)
     patches = _pc.extract_patches(xpad, spec.kh, spec.kw, spec.sh, spec.sw)
     b, ho, wo, _ = patches.shape
     flat = patches.reshape(b * ho * wo, -1).astype(x.dtype)
-    y = apply_linear(flat, p, module_quant(cfg, path),
-                     backend=None, path=path)
+    y = apply_linear(flat, p, module_quant(cfg, path), path=path)
     return y.reshape(b, ho, wo, spec.c_out).astype(x.dtype)
 
 

@@ -2,16 +2,15 @@
 
 Every projection weight is replaced by its PANN integer codes (Eq. 12,
 per-output-channel gamma) stored in int8 — b_R <= 5 bits in practice
-(Table 14), so int8 holds them losslessly — with dequant-on-load in the
-forward. This is the §Perf iteration-5 change: decode is memory-bound and
-weight-read bytes drop 2x vs bf16 (4x vs f32); the Pallas bit-plane kernel
-(repro.kernels.pann_matmul) realizes the full b_R-bit layout on TPU.
+(Table 14), so int8 holds them losslessly — and served through the integer
+kernels of ``repro.kernels.dispatch``. Decode is memory-bound: weight-read
+bytes drop 2x vs bf16 (4x vs f32) for the codes, and further for the
+bit-packed planes the 'packed' backend reads.
 
-By default activations stay in the compute dtype (W-PANN/A16); the PTQ
-accuracy story at matched power is measured separately in
-benchmarks/table2_ptq.py. Passing ``act_bits`` additionally quantizes
-activations at b~x in the forward (stored as a data leaf so serve-engine
-rungs share one compilation) — the full (b~x, R) operating point.
+``build_weight_store`` is the one quantizer: it quantizes once at each
+module's maximal budget and serves every rung of a ladder as a zero-copy
+view (DESIGN.md §11). ``quantize_params_for_serving`` is its one-rung form
+for a single operating point.
 """
 from __future__ import annotations
 
@@ -52,40 +51,11 @@ def _is_quant_parent(node: dict, trail: tuple) -> bool:
     name = trail[-1] if trail else ""
     return name in _QUANT_PARENTS or "conv_stem" in trail
 
-# Plane count used for ladder variant caches: int8 codes are clipped to
-# +-127 = 2^7 - 1, so 7 planes reconstruct EVERY rung's codes exactly AND
-# give every rung identical plane-leaf avals — the one-compiled-decode-step
-# invariant extends to the packed backend for free (values-only variants).
+# Plane count of every packed store: int8 codes are clipped to +-127 =
+# 2^7 - 1, so 7 planes reconstruct EVERY rung's codes exactly AND give every
+# rung identical plane-leaf avals — the one-compiled-decode-step invariant
+# extends to the packed backend for free (values-only views).
 LADDER_PLANE_COUNT = 7
-
-
-@dataclasses.dataclass(frozen=True)
-class ServingQuantSpec:
-    """Every serving-quantizer knob in ONE object — the single place new
-    knobs land, threaded through the engine, export, and fleet instead of
-    the historical kwarg sprawl on ``quantize_params_for_serving`` /
-    ``build_variant_cache`` / ``build_weight_store``.
-
-    ``policy`` / ``r`` + ``act_bits`` pick the operating point (a tree, or
-    one global (R, b~x)); the remaining fields mean exactly what the
-    same-named kwargs of ``quantize_params_for_serving`` document.
-    ``cache_bits`` additionally accepts a {rung key: bits} mapping when the
-    spec parameterizes a whole-ladder build. Pass as ``spec=`` to any of the
-    three builders; an explicit spec supersedes the individual kwargs.
-    """
-    policy: Optional[pol.PolicyTree] = None
-    r: Optional[float] = None
-    act_bits: Optional[int] = None
-    store_dtype: Any = jnp.int8
-    pack_planes: bool = False
-    plane_count: Optional[int] = None
-    calib: Optional[Mapping[str, Any]] = None
-    cache_bits: Any = None
-
-    def for_rung(self, cache_bits: Optional[int]) -> "ServingQuantSpec":
-        """The per-rung restriction a ladder builder hands the per-variant
-        quantizer: same knobs, this rung's resolved cache width."""
-        return dataclasses.replace(self, cache_bits=cache_bits)
 
 
 def _planes_artifact(codes, plane_count: int) -> dict:
@@ -106,8 +76,8 @@ def _planes_artifact(codes, plane_count: int) -> dict:
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("store_dtype", "pack"))
-def _store_leaf(w, r_max, *, store_dtype, pack: bool) -> dict:
+@functools.partial(jax.jit, static_argnames=("pack",))
+def _store_leaf(w, r_max, *, pack: bool) -> dict:
     """One projection's store leaves at budget ``r_max``: clipped int codes,
     gamma, and (``pack``) the plane stacks. Quantization is per output
     channel over the fan-in axis, so each (K, N) matrix of a scan-stacked
@@ -118,7 +88,7 @@ def _store_leaf(w, r_max, *, store_dtype, pack: bool) -> dict:
     def one(m):
         w_q, gamma = pann_core.pann_quantize(m, r_max, axis=0)
         codes = jnp.clip(w_q, -127, 127)
-        out = {"w_q": codes.astype(store_dtype),
+        out = {"w_q": codes.astype(jnp.int8),
                "w_scale": gamma.astype(jnp.float32)}
         if pack:
             out.update(_planes_artifact(codes, LADDER_PLANE_COUNT))
@@ -140,8 +110,7 @@ def _view_colsum(codes, shift):
 def _cache_artifact(stack, cache_role_bits, calib) -> dict:
     """Per-rung KV-cache leaves: level counts + (when the role was
     calibrated) hoisted quantizer scalars, stack-shaped so scan bodies can
-    slice them. One copy shared by the legacy per-rung quantizer and the
-    weight-store view builder."""
+    slice them."""
     out = {}
     for role, prefix in zip(pol.CACHE_PATHS, ("k", "v")):
         n_lvl = float(quant_core.cap_levels(cache_role_bits[role]))
@@ -158,9 +127,9 @@ def _cache_artifact(stack, cache_role_bits, calib) -> dict:
 
 def _act_leaves(stack, ab, trail, calib) -> dict:
     """Per-rung activation-quantizer leaves for one projection at b~x=ab:
-    level counts always, frozen range + hoisted (s, z) when calibrated.
-    Shared by the legacy quantizer and the view builder (identical op
-    sequences keep hoisted and derived scalars bit-exact)."""
+    level counts always, frozen range + hoisted (s, z) when calibrated
+    (the serve-time derivation's op sequence, so hoisted and derived
+    scalars are bit-exact)."""
     out = {
         # match the weight's stack dims (e.g. the vmapped group axis) so
         # scanned decode bodies can slice per group
@@ -189,145 +158,8 @@ def _act_leaves(stack, ab, trail, calib) -> dict:
     return out
 
 
-def quantize_params_for_serving(params: Any, cfg: ModelConfig,
-                                r: float | None = None,
-                                act_bits: int | None = None,
-                                policy: Optional[pol.PolicyTree] = None,
-                                store_dtype=jnp.int8,
-                                pack_planes: bool = False,
-                                plane_count: Optional[int] = None,
-                                calib: Optional[Mapping[str, Any]] = None,
-                                cache_bits: Optional[int] = None,
-                                spec: Optional[ServingQuantSpec] = None
-                                ) -> Any:
-    """Walk the param tree; replace {"w": W} under known projections with
-    {"w_q": int codes, "w_scale": gamma}. MoE stacked experts and the
-    embedding gather table stay in floating point (documented).
-
-    ``act_bits`` (b~x) additionally stores ``act_n = 2^b~x - 1`` per
-    projection so the forward quantizes activations at the operating point's
-    bit width; it is a data leaf, not a shape/dtype change, so serve-engine
-    rungs with different b~x still share one compiled decode step. Without
-    ``act_bits`` the artifact is W-PANN-only (activations in compute dtype),
-    the legacy single-point behavior.
-
-    ``policy`` (a ``core.policy.PolicyTree``) quantizes each projection at
-    ITS OWN (R, b~x): the key trail through the pytree is mapped to the
-    canonical module path (``policy.serving_path``) and the looked-up
-    ``ModuleQuant`` supplies that projection's point. Since only leaf
-    VALUES change — never shapes, dtypes, or the tree structure — a
-    layerwise variant shares the decode-step compilation with every uniform
-    variant (the serve_engine invariant).
-
-    ``pack_planes`` additionally materializes the bit-packed plane artifact
-    (``w_planes_pos``/``w_planes_neg`` uint8 leaves) the 'packed' kernel
-    backend reads — 2 * P / 8 bytes per weight for plane count P.
-    ``plane_count`` pins P; None derives each module's value-exact b_R
-    (minimal HBM, single-point artifacts), while ladder caches pass
-    ``LADDER_PLANE_COUNT`` so every rung shares plane-leaf avals. Codes are
-    clipped to the planes' +-(2^P - 1) envelope (a no-op at P = 7, the int8
-    range) so ``w_q`` and the planes always describe the SAME weights —
-    the backends' bit-exactness contract.
-
-    ``calib`` (an EMA activation-range collection from power-aware QAT,
-    ``core.calibrate`` / ``launch/export.py``) freezes each projection's
-    activation range into ``act_lo``/``act_hi`` leaves: the forward then
-    quantizes against the SAME static ranges training converged on instead
-    of the per-batch dynamic range — the train→serve closing move. Roles
-    the training run never observed (lo > hi) stay dynamic. Requires an
-    activation bit width (``act_bits`` or a ``policy``) so ``act_n`` is
-    materialized alongside.
-
-    ``cache_bits`` — or a ``policy`` with EXPLICIT cache-role overrides
-    (``policy.CACHE_PATHS``; prefix fallback from "attn" is deliberately
-    NOT an opt-in) — attaches a ``kv_cache`` artifact dict under every
-    self-attention parent: per-role ``k_nlvl``/``v_nlvl`` DATA leaves (the
-    rung's cache level counts, stack-shaped like ``act_n`` so scan bodies
-    slice them) plus, when ``calib`` saw the cache roles, frozen quantizer
-    scalars ``k_s``/``k_z``/``v_s``/``v_z`` hoisted with the identical
-    ``affine_scale_zp`` op sequence the decode step would run. ``xattn``
-    parents are skipped: cross-attention K/V are precomputed fp encoder
-    projections, not a decode-time cache.
-
-    ``spec`` (a ``ServingQuantSpec``) names the same knobs as one object
-    and supersedes the individual kwargs."""
-    if spec is not None:
-        policy, r, act_bits = spec.policy, spec.r, spec.act_bits
-        store_dtype, pack_planes = spec.store_dtype, spec.pack_planes
-        plane_count, calib = spec.plane_count, spec.calib
-        cache_bits = spec.cache_bits
-    if policy is None:
-        r = r if r is not None else cfg.quant.r
-    if calib:
-        if act_bits is None and policy is None:
-            raise ValueError(
-                "freezing calibrated ranges needs an activation bit width: "
-                "pass act_bits= or a policy= tree")
-        calib = {k: np.asarray(v, np.float32) for k, v in calib.items()}
-
-    cache_role_bits = None
-    policy_cache = pol.tree_cache_bits(policy) if policy is not None else {}
-    if policy_cache or cache_bits is not None:
-        default_b = cache_bits if cache_bits is not None else max(
-            policy_cache.values())
-        cache_role_bits = {
-            role: int(policy_cache.get(role, default_b))
-            for role in pol.CACHE_PATHS}
-
-    def cache_artifact(stack) -> dict:
-        return _cache_artifact(stack, cache_role_bits, calib)
-
-    def walk(node, trail=()):
-        if isinstance(node, dict):
-            name = trail[-1] if trail else ""
-            if _is_quant_parent(node, trail):
-                w = node["w"]
-                if policy is not None:
-                    mq = policy.lookup(pol.serving_path(trail))
-                    r_mod, ab = mq.r, mq.b_x_tilde
-                else:
-                    r_mod, ab = r, act_bits
-                w_q, gamma = pann_core.pann_quantize(
-                    w.astype(jnp.float32), float(r_mod), axis=w.ndim - 2)
-                codes = jnp.clip(w_q, -127, 127)
-                if pack_planes:
-                    p_cnt = plane_count if plane_count is not None else \
-                        pann_core.weight_storage_bits(codes)
-                    cap = (1 << min(int(p_cnt), 7)) - 1
-                    codes = jnp.clip(codes, -cap, cap)
-                out = {
-                    "w_q": codes.astype(store_dtype),
-                    "w_scale": gamma.astype(jnp.float32),
-                    # per-output-channel code sum, precomputed so the kernel
-                    # backends' zero-point row (dispatch: zcol = z * colsum)
-                    # never re-reads the code tensor at decode time — for
-                    # 'packed' that read would dwarf the plane bytes
-                    "w_colsum": jnp.sum(codes.astype(jnp.int32), axis=-2),
-                }
-                if pack_planes:
-                    out.update(_planes_artifact(codes, int(p_cnt)))
-                if ab is not None:
-                    out.update(_act_leaves(w.shape[:-2], ab, trail, calib))
-                if "b" in node:
-                    out["b"] = node["b"]
-                return out
-            out = {k: walk(v, trail + (k,)) for k, v in node.items()}
-            if (cache_role_bits is not None
-                    and name in ("attn", "shared_attn") and "wk" in node
-                    and isinstance(node["wk"], dict) and "w" in node["wk"]):
-                out["kv_cache"] = cache_artifact(node["wk"]["w"].shape[:-2])
-            return out
-        if isinstance(node, list):
-            return [walk(v, trail) for v in node]
-        if isinstance(node, tuple):
-            return tuple(walk(v, trail) for v in node)
-        return node
-
-    return walk(params)
-
-
 # ---------------------------------------------------------------------------
-# Operating-point variant cache (serve_engine)
+# Max-R weight store + zero-copy rung views (DESIGN.md §11)
 # ---------------------------------------------------------------------------
 
 def variant_shardings(variant: Any, mesh, par: Optional[ParallelConfig] = None
@@ -340,92 +172,6 @@ def variant_shardings(variant: Any, mesh, par: Optional[ParallelConfig] = None
     specs = shardlib.param_specs(shapes, mesh, par or ParallelConfig())
     return shardlib.to_named(specs, mesh)
 
-
-def build_variant_cache(params: Any, cfg: ModelConfig,
-                        r_by_rung: Mapping[Any, Any],
-                        mesh=None, par: Optional[ParallelConfig] = None,
-                        store_dtype=jnp.int8,
-                        pack_planes: bool = False,
-                        plane_count: Optional[int] = None,
-                        calib: Optional[Mapping[str, Any]] = None,
-                        cache_bits: Any = None,
-                        spec: Optional[ServingQuantSpec] = None) -> dict:
-    """Materialize one int8 weight-code variant per operating point.
-
-    ``r_by_rung`` maps a rung key (e.g. the unsigned-MAC bit budget) to the
-    rung's PANN addition budget R, to ``(R, b~x)`` to also quantize
-    activations at the rung's bit width, or to a ``core.policy.PolicyTree``
-    for a layerwise rung (each projection at its own per-module (R, b~x)).
-    All variants share one pytree structure and one set of avals (b~x is
-    stored as data, not shape), so a single jitted decode step serves every
-    rung — switching rungs is a pointer swap, never a retrace. With a
-    ``mesh``, each variant is device_put with the training-param layout so
-    the cache scales past one device instead of replicating N ladders.
-
-    ``pack_planes`` adds the uint8 plane leaves for the 'packed' kernel
-    backend; callers must pin ``plane_count`` (e.g. ``LADDER_PLANE_COUNT``)
-    so every rung's plane leaves share avals — a value-exact per-rung count
-    would retrace the decode step at every rung switch.
-
-    ``calib`` freezes EMA-calibrated activation ranges into every rung (see
-    ``quantize_params_for_serving``); since the range leaves are values,
-    not avals, calibrated and uncalibrated rungs still share one compiled
-    decode step — but every rung in ONE cache must agree on which roles are
-    calibrated (same leaf set), which passing one collection guarantees.
-
-    ``cache_bits`` quantizes the decode-time KV cache per rung: an int
-    applies to every rung, a mapping (rung key -> bits) gives each rung its
-    own cache width — still one compiled step, because the width rides in
-    the ``k_nlvl``/``v_nlvl`` DATA leaves. All-or-none across rungs (a rung
-    without cache leaves would change the pytree structure); PolicyTree
-    rungs may instead carry explicit cache-role overrides.
-
-    ``spec`` (a ``ServingQuantSpec``) supersedes the per-knob kwargs.
-    """
-    if spec is not None:
-        store_dtype, pack_planes = spec.store_dtype, spec.pack_planes
-        plane_count, calib = spec.plane_count, spec.calib
-        cache_bits = spec.cache_bits
-    if isinstance(cache_bits, Mapping):
-        missing = set(r_by_rung) - set(cache_bits)
-        if missing:
-            raise ValueError(
-                f"cache_bits mapping must cover every rung (missing "
-                f"{sorted(missing)}): rungs with and without kv_cache "
-                "leaves cannot share one pytree structure")
-    if pack_planes and plane_count is None and len(r_by_rung) > 1:
-        raise ValueError(
-            "pack_planes over multiple rungs needs a pinned plane_count "
-            "(e.g. serving.LADDER_PLANE_COUNT); per-rung value-exact plane "
-            "counts give rungs different avals and break the one-compiled-"
-            "decode-step invariant")
-    base = ServingQuantSpec(store_dtype=store_dtype,
-                            pack_planes=pack_planes,
-                            plane_count=plane_count, calib=calib)
-    cache = {}
-    shardings = None
-    for key, rung_spec in r_by_rung.items():
-        cb = (cache_bits.get(key) if isinstance(cache_bits, Mapping)
-              else cache_bits)
-        rq = base.for_rung(None if cb is None else int(cb))
-        if isinstance(rung_spec, pol.PolicyTree):
-            rq = dataclasses.replace(rq, policy=rung_spec)
-        else:
-            r, act_bits = rung_spec if isinstance(rung_spec, tuple) \
-                else (rung_spec, None)
-            rq = dataclasses.replace(rq, r=float(r), act_bits=act_bits)
-        v = quantize_params_for_serving(params, cfg, spec=rq)
-        if mesh is not None:
-            if shardings is None:     # variants share avals: compute once
-                shardings = variant_shardings(v, mesh, par)
-            v = jax.device_put(v, shardings)
-        cache[key] = v
-    return cache
-
-
-# ---------------------------------------------------------------------------
-# Max-R weight store + zero-copy rung views (DESIGN.md §11)
-# ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
 class WeightStore:
@@ -452,9 +198,8 @@ class WeightStore:
 
 
 def _resolve_point(spec, trail) -> tuple[float, Optional[int]]:
-    """One rung spec -> (R, b~x) for the module at ``trail`` — the same
-    three spellings ``build_variant_cache`` accepts (PolicyTree / (R, b~x) /
-    bare R)."""
+    """One rung spec -> (R, b~x) for the module at ``trail``: a
+    PolicyTree, an (R, b~x) pair, or a bare R (b~x None)."""
     if isinstance(spec, pol.PolicyTree):
         mq = spec.lookup(pol.serving_path(trail))
         return float(mq.r), int(mq.b_x_tilde)
@@ -478,34 +223,48 @@ def _rung_cache_role_bits(spec, cb: Optional[int]) -> Optional[dict]:
 
 def build_weight_store(params: Any, cfg: ModelConfig,
                        r_by_rung: Mapping[Any, Any],
-                       mesh=None, par: Optional[ParallelConfig] = None,
-                       store_dtype=jnp.int8,
+                       *, mesh=None, par: Optional[ParallelConfig] = None,
                        pack_planes: bool = False,
                        calib: Optional[Mapping[str, Any]] = None,
-                       cache_bits: Any = None,
-                       spec: Optional[ServingQuantSpec] = None
-                       ) -> WeightStore:
+                       cache_bits: Any = None) -> WeightStore:
     """Quantize once at the per-module max budget; realize every rung of
     ``r_by_rung`` as a view over that single store (see ``WeightStore``).
 
-    Accepts the same rung-spec / ``calib`` / ``cache_bits`` spellings as
-    ``build_variant_cache`` and produces views with the legacy variants'
-    pytree structure plus one extra data leaf per projection
-    (``plane_shift``) — all views share avals, so the one-compiled-decode-
-    step invariant holds across mixed weight-rung x cache-rung ladders.
-    Plane leaves (``pack_planes``) are always built at ``LADDER_PLANE_COUNT``
-    so the full truncation envelope is stored.
+    ``r_by_rung`` maps a rung key (e.g. the unsigned-MAC bit budget) to the
+    rung's PANN addition budget R, to ``(R, b~x)`` to also quantize
+    activations at the rung's bit width, or to a ``core.policy.PolicyTree``
+    for a layerwise rung (each projection at its own (R, b~x), looked up by
+    its canonical module path ``policy.serving_path``). Each projection
+    {"w": W} becomes {"w_q": int8 PANN codes (Eq. 12, per-output-channel
+    gamma), "w_scale": gamma, "w_colsum", "plane_shift"}; MoE stacked
+    experts and the embedding table stay in floating point. A rung's b~x is
+    stored as data (``act_n``/``act_nlvl``), never as shape, and every view
+    shares one pytree structure and one set of avals, so one compiled
+    decode step serves the whole ladder.
+
+    ``pack_planes`` adds the uint8 plane leaves (``w_planes_pos``/
+    ``w_planes_neg``) the 'packed' kernel backend reads, always at
+    ``LADDER_PLANE_COUNT`` so the full truncation envelope is stored.
+
+    ``calib`` (an EMA activation-range collection from power-aware QAT,
+    ``core.calibrate`` / ``launch/export.py``) freezes each projection's
+    activation range into ``act_lo``/``act_hi`` (+ hoisted ``act_s``/
+    ``act_z``) leaves, so serving quantizes against the ranges training
+    converged on; roles the run never observed (lo > hi) stay dynamic.
+
+    ``cache_bits`` quantizes the decode-time KV cache: an int applies to
+    every rung, a mapping (rung key -> bits) gives each rung its own width;
+    PolicyTree rungs may instead carry explicit cache-role overrides
+    (``policy.CACHE_PATHS``). Each self-attention parent then gets a
+    ``kv_cache`` dict of per-role level counts (+ frozen quantizer scalars
+    when ``calib`` saw the cache roles). All-or-none across rungs: a rung
+    without cache leaves would change the pytree structure.
 
     With a ``mesh`` the store is device_put ONCE under the training param
     rules; views then alias the store's device buffers and only their small
     per-rung leaves are placed separately — the flat-HBM property survives
     sharding.
-
-    ``spec`` (a ``ServingQuantSpec``) supersedes the per-knob kwargs.
     """
-    if spec is not None:
-        store_dtype, pack_planes = spec.store_dtype, spec.pack_planes
-        calib, cache_bits = spec.calib, spec.cache_bits
     if isinstance(cache_bits, Mapping):
         missing = set(r_by_rung) - set(cache_bits)
         if missing:
@@ -542,7 +301,6 @@ def build_weight_store(params: Any, cfg: ModelConfig,
                           for k in keys}
                 r_max = max(r for r, _ in points.values())
                 shared = _store_leaf(w, jnp.float32(r_max),
-                                     store_dtype=store_dtype,
                                      pack=pack_planes)
                 codes = shared["w_q"]
                 if "b" in node:
@@ -606,6 +364,60 @@ def build_weight_store(params: Any, cfg: ModelConfig,
     return WeightStore(store=store, views=view_trees)
 
 
+def _map_projections(fn, view: Any) -> Any:
+    """``view`` with ``fn`` applied to every projection artifact (each dict
+    holding a ``plane_shift`` leaf)."""
+    def walk(node):
+        if isinstance(node, dict):
+            if "plane_shift" in node:
+                return fn(node)
+            return {k: walk(v) for k, v in node.items()}
+        if isinstance(node, list):
+            return [walk(v) for v in node]
+        if isinstance(node, tuple):
+            return tuple(walk(v) for v in node)
+        return node
+
+    return walk(view)
+
+
+def quantize_params_for_serving(params: Any, cfg: ModelConfig, *,
+                                r: float | None = None,
+                                act_bits: int | None = None,
+                                policy: Optional[pol.PolicyTree] = None,
+                                pack_planes: bool = False,
+                                calib: Optional[Mapping[str, Any]] = None,
+                                cache_bits: Optional[int] = None) -> Any:
+    """One operating point's serving artifact: the single view of a
+    one-rung ``build_weight_store``, without its ``plane_shift`` leaves.
+
+    The point is ``policy`` (a PolicyTree), else (``r``, ``act_bits``) with
+    ``r`` defaulting to ``cfg.quant.r``; without ``act_bits`` the artifact
+    carries no activation leaves and the kernels quantize activations at
+    their 7-bit half-range ceiling. ``calib`` and ``cache_bits`` mean what
+    they mean for ``build_weight_store``; freezing calibrated ranges needs
+    an activation bit width. ``pack_planes`` packs each module's planes at
+    its value-exact count b_R (Table 14), the smallest single-point
+    artifact."""
+    if calib and act_bits is None and policy is None:
+        raise ValueError(
+            "freezing calibrated ranges needs an activation bit width: "
+            "pass act_bits= or a policy= tree")
+    point = policy if policy is not None else (
+        float(r if r is not None else cfg.quant.r), act_bits)
+    view = build_weight_store(params, cfg, {0: point}, calib=calib,
+                              cache_bits=cache_bits).views[0]
+
+    def one_point(node):
+        out = {k: v for k, v in node.items() if k != "plane_shift"}
+        if pack_planes:
+            out.update(_planes_artifact(
+                out["w_q"], pann_core.weight_storage_bits(out["w_q"])))
+        return out
+
+    return _map_projections(one_point, view)
+
+
 def device_put_weight_store(ws: WeightStore, mesh=None,
                             par: Optional[ParallelConfig] = None
                             ) -> WeightStore:
@@ -646,31 +458,21 @@ def device_put_weight_store(ws: WeightStore, mesh=None,
 
 
 def materialize_view(view: Any) -> Any:
-    """Copy one rung view out into a standalone legacy-format variant:
+    """Copy one rung view out into a standalone single-point artifact:
     ``w_q`` becomes the masked codes the plane-skipping kernels realize
     (``core.pann.masked_codes``), plane leaves are re-packed from them, and
     the ``plane_shift`` leaf is dropped. Same gamma_R scale, same bias grid,
     same integer dataflow — the decode outputs are bit-identical to running
     the view itself, which tests/test_artifact.py asserts per module and
     per backend."""
-    def walk(node):
-        if isinstance(node, dict):
-            if "w_q" in node and "plane_shift" in node:
-                sh = jnp.asarray(node["plane_shift"],
-                                 jnp.int32).reshape(-1)[0]
-                masked = pann_core.masked_codes(node["w_q"], sh)
-                out = {k: v for k, v in node.items() if k != "plane_shift"}
-                out["w_q"] = masked.astype(node["w_q"].dtype)
-                out["w_colsum"] = jnp.sum(masked, axis=-2)
-                if "w_planes_pos" in node:
-                    out.update(
-                        _planes_artifact(masked, LADDER_PLANE_COUNT))
-                return out
-            return {k: walk(v) for k, v in node.items()}
-        if isinstance(node, list):
-            return [walk(v) for v in node]
-        if isinstance(node, tuple):
-            return tuple(walk(v) for v in node)
-        return node
+    def one(node):
+        sh = jnp.asarray(node["plane_shift"], jnp.int32).reshape(-1)[0]
+        masked = pann_core.masked_codes(node["w_q"], sh)
+        out = {k: v for k, v in node.items() if k != "plane_shift"}
+        out["w_q"] = masked.astype(node["w_q"].dtype)
+        out["w_colsum"] = jnp.sum(masked, axis=-2)
+        if "w_planes_pos" in node:
+            out.update(_planes_artifact(masked, LADDER_PLANE_COUNT))
+        return out
 
-    return walk(view)
+    return _map_projections(one, view)

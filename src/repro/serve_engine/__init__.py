@@ -22,7 +22,7 @@ package turns that claim into a serving runtime:
               (manifest.json + weights.bin; docs/artifact.md)
   fleet       ``Fleet``: ServeEngine replicated across simulated hosts on
               ``repro.dist`` — prefill/decode disaggregation, rung-sharded
-              variant caches served from ONE mmap artifact, and a
+              rung views served from ONE mmap artifact, and a
               telemetry-driven power governor holding the whole fleet
               under a global Gbit-flips/sec cap (docs/fleet.md)
 

@@ -72,16 +72,15 @@ class EncodeEngine:
                  max_batch: int = 4, mesh=None, par=None,
                  mse_dim: Optional[float] = None,
                  allocation: str = "uniform",
-                 backend: Optional[str] = None,
+                 backend: str = "packed",
                  weight_store: Optional[serving.WeightStore] = None):
         if (params is None) == (weight_store is None):
             raise ValueError(
                 "pass exactly one of params (quantize here) or "
                 "weight_store (serve a prebuilt/loaded artifact)")
+        dispatch.parse_backend(backend)          # fail fast on typos
         self.backend = backend
-        if backend is not None:
-            dispatch.parse_backend(backend)      # fail fast on typos
-            cfg = dataclasses.replace(cfg, kernel_backend=backend)
+        cfg = dataclasses.replace(cfg, kernel_backend=backend)
         self.cfg = cfg
         self.max_batch = int(max_batch)
         self.allocation = allocation
@@ -99,8 +98,6 @@ class EncodeEngine:
                                    allocation=allocation,
                                    profile=self.profile)
         self.rungs = {op.bits: op for op in self.ladder}
-        needs_planes = (backend is not None
-                        and dispatch.parse_backend(backend)[0] == "packed")
         rung_specs = {op.bits: (op.tree if op.tree is not None
                                 else (op.r, op.b_x_tilde))
                       for op in self.ladder}
@@ -116,10 +113,9 @@ class EncodeEngine:
                     views={b: weight_store.views[b] for b in rung_specs}),
                 mesh=mesh, par=par)
         else:
-            quant_spec = serving.ServingQuantSpec(pack_planes=needs_planes)
-            ws = serving.build_weight_store(params, cfg, rung_specs,
-                                            mesh=mesh, par=par,
-                                            spec=quant_spec)
+            ws = serving.build_weight_store(
+                params, cfg, rung_specs, mesh=mesh, par=par,
+                pack_planes=dispatch.reads_planes(backend))
         self.weight_store = ws.store
         self.variants = ws.views
         # ONE jitted whole-sequence encode — every rung's view shares its

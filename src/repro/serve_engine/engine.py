@@ -2,13 +2,13 @@
 power-accuracy selection — with no re-quantization and no recompilation after
 warmup.
 
-Why switching is free (DESIGN.md §6): every rung's variant is produced by
-``models/serving.py`` with the same pytree structure, shapes, and dtypes
-(int8 codes + f32 scales); jax.jit keys its compilation cache on exactly
-those avals, so ONE traced decode step serves every rung and moving between
-rungs is a pointer swap into the variant cache. ``warmup()`` runs each rung
-once and records the jit cache size; ``assert_no_recompile()`` proves the
-claim after serving mixed traffic.
+Why switching is free (DESIGN.md §6): every rung's view of the one weight
+store (``models/serving.build_weight_store``) has the same pytree structure,
+shapes, and dtypes (int8 codes + f32 scales); jax.jit keys its compilation
+cache on exactly those avals, so ONE traced decode step serves every rung
+and moving between rungs is a pointer swap between views. ``warmup()`` runs
+each rung once and records the jit cache size; ``assert_no_recompile()``
+proves the claim after serving mixed traffic.
 
 The engine interleaves *lanes* (one per in-flight wave) round-robin, one
 decode step each — so a 2-bit lane and a 6-bit lane genuinely alternate
@@ -76,9 +76,6 @@ class Lane:
         return np.asarray(jnp.concatenate(self.generated, axis=1))[:n]
 
 
-_Lane = Lane                  # pre-fleet private name (back-compat)
-
-
 def _span(name: str, wave: Wave):
     """A ``serve.*`` host span of ``wave`` in the profiler's trace."""
     return jax.profiler.TraceAnnotation(name, wave=wave.seq,
@@ -93,10 +90,9 @@ class ServeEngine:
                  max_batch: int = 4, max_len: int = 64, mesh=None,
                  par=None, mse_dim: Optional[float] = None,
                  allocation: str = "uniform",
-                 backend: Optional[str] = None,
+                 backend: str = "packed",
                  autotune: bool = False,
                  cache_bits: Any = None,
-                 artifact_format: str = "views",
                  weight_store: Optional[serving.WeightStore] = None,
                  frontend_kwargs_fn: Optional[Callable[[int], dict]] = None):
         if (params is None) == (weight_store is None):
@@ -127,11 +123,12 @@ class ServeEngine:
                 cfg, cache_bits=7 if cache_bits == "auto" else cache_bits)
         # the serving-matmul backend (repro.kernels.dispatch) is trace-time
         # static on the config: ONE jitted decode step per backend, still
-        # one per ENGINE — every rung of this ladder shares it
+        # one per ENGINE — every rung of this ladder shares it. The default
+        # 'packed' is the platform's own choice: the Pallas kernel on TPU,
+        # 'ref' elsewhere (dispatch.resolve_backend)
+        dispatch.parse_backend(backend)          # fail fast on typos
         self.backend = backend
-        if backend is not None:
-            dispatch.parse_backend(backend)      # fail fast on typos
-            cfg = dataclasses.replace(cfg, kernel_backend=backend)
+        cfg = dataclasses.replace(cfg, kernel_backend=backend)
         self.cfg = cfg
         self.max_batch = int(max_batch)
         self.max_len = int(max_len)
@@ -149,9 +146,9 @@ class ServeEngine:
                                    allocation=allocation,
                                    profile=alloc_profile)
         self.rungs = {op.bits: op for op in self.ladder}
-        # per-rung cache width handed to the variant cache: an int pins the
+        # per-rung cache width handed to the weight store: an int pins the
         # rung's k_nlvl/v_nlvl leaves; None defers to the rung's PolicyTree
-        # cache-role overrides (quantize_params_for_serving reads those)
+        # cache-role overrides (build_weight_store reads those)
         self._cache_bits_by_rung: dict[int, Optional[int]] = {}
         if cache_bits is not None:
             for op in self.ladder:
@@ -162,36 +159,18 @@ class ServeEngine:
                 else:
                     self._cache_bits_by_rung[op.bits] = min(
                         int(op.b_x_tilde), 7)
-        # the variant cache: int8 weight codes per rung, activations
-        # quantized at the rung's b~x (stored as data so rungs share one
-        # compilation), sharded like training params on a mesh; a layerwise
-        # rung materializes per-module (R, b~x) codes via its PolicyTree —
-        # same pytree structure and avals, so it still shares the one
-        # compiled decode step with every uniform rung
-        # par: the training ParallelConfig, so an FSDP-trained layout and
-        # the serving cache layout can't drift apart
-        # the 'packed' backend reads bit-packed plane leaves; the pinned
-        # LADDER_PLANE_COUNT keeps plane avals identical across rungs
-        needs_planes = (backend is not None
-                        and dispatch.parse_backend(backend)[0] == "packed")
+        # The ladder is ONE weight store with zero-copy rung views: quantize
+        # ONCE at the per-module max budget and realize every rung as a view
+        # adding only small data leaves (activations quantized at the rung's
+        # b~x, stored as data) — HBM independent of ladder depth, rung
+        # budgets snapped to powers of two of the top rung, one pytree
+        # structure and one set of avals, so every rung shares the one
+        # compiled decode step (DESIGN.md §11). On a mesh it is sharded like
+        # training params (``par``: the training ParallelConfig).
+        needs_planes = dispatch.reads_planes(backend)
         rung_specs = {op.bits: (op.tree if op.tree is not None
                                 else (op.r, op.b_x_tilde))
                       for op in self.ladder}
-        # The ladder is materialized as ONE weight store with zero-copy rung
-        # views: quantize ONCE at the per-module max budget, realize every
-        # rung as a view adding only small data leaves — HBM independent of
-        # ladder depth, rung budgets snapped to powers of two of the top
-        # rung (DESIGN.md §11). The per-rung "legacy" quantizer was retired
-        # (benchmarks/artifact_parity.py bounds the snapping drift in
-        # closed form; serving it cost N full stores for no exactness win).
-        if artifact_format != "views":
-            raise ValueError(
-                f"artifact_format {artifact_format!r} is gone: the per-rung "
-                "'legacy' materialization was retired — 'views' (one weight "
-                "store, zero-copy rung views) is the only format. Budget "
-                "snapping drift is bounded by benchmarks/artifact_parity.py; "
-                "drop the artifact_format argument.")
-        self.artifact_format = artifact_format
         if weight_store is not None:
             # serve a prebuilt store — typically artifact.load_artifact's
             # mmap-backed views (ROADMAP item 5: no re-quantization on the
@@ -220,12 +199,10 @@ class ServeEngine:
             self.weight_store = ws.store
             self.variants = ws.views
         else:
-            quant_spec = serving.ServingQuantSpec(
+            ws = serving.build_weight_store(
+                params, cfg, rung_specs, mesh=mesh, par=par,
                 pack_planes=needs_planes,
                 cache_bits=self._cache_bits_by_rung or None)
-            ws = serving.build_weight_store(params, cfg, rung_specs,
-                                            mesh=mesh, par=par,
-                                            spec=quant_spec)
             self.weight_store = ws.store
             self.variants = ws.views
         # offline block autotuning (kernels/autotune): measure-and-cache the
@@ -233,8 +210,7 @@ class ServeEngine:
         # ever traced — serving_linear then reads the cache at trace time,
         # so tuning never invalidates the one-compiled-decode-step claim
         # (all rungs share avals, hence shapes, hence tuning decisions)
-        if autotune and backend is not None \
-                and dispatch.parse_backend(backend)[0] != "ref":
+        if autotune and dispatch.parse_backend(backend)[0] != "ref":
             self._autotune_projections()
         self._frontend_kwargs_fn = frontend_kwargs_fn
 
@@ -433,9 +409,6 @@ class ServeEngine:
                 tok = self._greedy(logits)
         return Lane(wave=wave, state=state, tok=tok, generated=[tok],
                     steps_left=gen_max - done - 1, done=done)
-
-    _prefill = prefill_wave       # pre-fleet private name (back-compat)
-
     def step_lane(self, lane: Lane) -> bool:
         """Advance a lane one decode step; True when the lane is finished.
         One step serves every live row of the wave — the fleet's unit of
@@ -493,9 +466,6 @@ class ServeEngine:
             # cache-aware total stands on its own there.
             total = pw.pann_token_bitflips(macs, rung.r, rung.b_x_tilde)
         return pw.EnergyLedger(total, breakdown_per_token=breakdown)
-
-    _ledger_for = ledger_for      # pre-fleet private name (back-compat)
-
     def token_flips(self, bits: int, ctx: int) -> float:
         """Estimated bit flips of ONE token at rung ``bits`` with context
         ``ctx`` — the deterministic per-step price the fleet governor
@@ -504,13 +474,13 @@ class ServeEngine:
         violations a structural property, not a measurement)."""
         return self.ledger_for(self.rungs[bits], ctx).bitflips_per_token
 
-    def _finalize(self, lane: _Lane) -> list[Response]:
+    def _finalize(self, lane: Lane) -> list[Response]:
         gen = np.asarray(jnp.concatenate(lane.generated, axis=1))
         rung = lane.wave.rung
         out = []
         for i, req in enumerate(lane.wave.requests):
             toks = gen[i, :req.max_new_tokens].tolist()
-            ledger = self._ledger_for(rung, req.prompt_len
+            ledger = self.ledger_for(rung, req.prompt_len
                                       + req.max_new_tokens)
             ledger.charge(len(toks))
             meta = {
@@ -611,10 +581,8 @@ class ServeEngine:
         total_macs = sum(m.macs for m in self.profile)
         return {
             "allocation": self.allocation,
-            "artifact_format": self.artifact_format,
-            "backend": self.backend or "legacy",
-            "effective_backend": (dispatch.effective_backend(self.backend)
-                                  if self.backend else "legacy"),
+            "backend": self.backend,
+            "effective_backend": dispatch.effective_backend(self.backend),
             "cache_bits": self.cache_bits,
             "cache_bits_by_rung": dict(self._cache_bits_by_rung) or None,
             "ladder": [{"bits": op.bits, "b_x_tilde": op.b_x_tilde,

@@ -3,7 +3,7 @@
 ``Fleet`` runs ``ServeEngine`` replicas across a simulated mesh of hosts on
 the ``repro.dist`` substrate: prefill and decode are disaggregated
 (prefill hosts teacher-force prompts and hand finished lanes to decode
-hosts), the per-rung variant cache is SHARDED across decode hosts
+hosts), the ladder's rung views are SHARDED across decode hosts
 (``dist.sharding.rung_shard`` — each host warms only its rung shard), and
 every host serves zero-copy views out of ONE mmap serving artifact
 (``serve_engine.artifact``) — a restarted host resumes from the same
@@ -359,7 +359,7 @@ class FleetConfig:
     max_batch: int = 2
     max_len: int = 48
     rung_sharding: bool = True
-    backend: Optional[str] = None
+    backend: str = "packed"
     cache_bits: Optional[int] = None
     max_restarts_per_host: int = 3
     drain_tick_factor: int = 10    # stall guard: max ticks / trace ticks
@@ -401,17 +401,14 @@ class Fleet:
         if params is not None:
             # quantize ONCE, persist as the mmap artifact all hosts map
             from repro.kernels import dispatch
-            needs_planes = (fc.backend is not None and
-                            dispatch.parse_backend(fc.backend)[0]
-                            == "packed")
             specs = {op.bits: (op.tree if op.tree is not None
                                else (op.r, op.b_x_tilde))
                      for op in self.ladder}
             cb = ({op.bits: fc.cache_bits for op in self.ladder}
                   if fc.cache_bits is not None else None)
-            qspec = serving.ServingQuantSpec(pack_planes=needs_planes,
-                                             cache_bits=cb)
-            ws = serving.build_weight_store(params, cfg, specs, spec=qspec)
+            ws = serving.build_weight_store(
+                params, cfg, specs,
+                pack_planes=dispatch.reads_planes(fc.backend), cache_bits=cb)
             afct.write_artifact(artifact_dir, ws,
                                 meta={"fleet_ladder": list(fc.ladder_bits)})
         self._load_artifact = lambda: afct.load_artifact(artifact_dir)

@@ -284,19 +284,20 @@ def test_rejects_missing_manifest(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_engine_views_only_legacy_retired(setup):
+    """The engine's rung views alias ONE store's big leaves: each code and
+    plane tensor is held once, whatever the ladder depth."""
     cfg, params = setup
     eng = ServeEngine(cfg, params, ladder_bits=(2, 6), max_batch=1,
                       max_len=12)
-    assert eng.artifact_format == "views"
-    assert eng.weight_store is not None
-    # the per-rung "legacy" materialization is retired: the name now gets
-    # a helpful error pointing at the views format + the parity bound
-    with pytest.raises(ValueError, match="retired"):
-        ServeEngine(cfg, params, ladder_bits=(2, 6), max_batch=1,
-                    max_len=12, artifact_format="legacy")
-    with pytest.raises(ValueError, match="artifact_format"):
-        ServeEngine(cfg, params, ladder_bits=(2, 6), max_batch=1,
-                    max_len=12, artifact_format="mmap")
+    store_ids = {id(leaf) for _, leaf in _leaves(eng.weight_store)}
+    big = {"w_q", "w_scale"} | set(dispatch.PLANE_KEYS)
+    n = 0
+    for view in eng.variants.values():
+        for path, leaf in _leaves(view):
+            if getattr(path[-1], "key", "") in big:
+                assert id(leaf) in store_ids, jax.tree_util.keystr(path)
+                n += 1
+    assert n > 0 and len(eng.variants) == 2
 
 
 def test_engine_serves_loaded_artifact_bit_identically(setup, ws, written):

@@ -80,8 +80,8 @@ def test_conv_out_size_rejects_empty_output():
 def test_serving_conv_bit_identical_to_oracle(setup, backend):
     cfg, params, raw = setup
     sp = serving.quantize_params_for_serving(
-        params, cfg, spec=serving.ServingQuantSpec(
-            r=4.0, act_bits=6, pack_planes=backend.startswith("packed")))
+        params, cfg, r=4.0, act_bits=6,
+        pack_planes=dispatch.reads_planes(backend))
     for i, spec in enumerate(cfg.conv_stem):
         p = sp["conv_stem"][f"s{i}"]
         x = raw if i == 0 else jnp.zeros(
@@ -99,8 +99,7 @@ def test_serving_conv_exact_across_rung_views(setup, backend):
     vs the oracle THROUGH the view's plane_shift masking, per rung."""
     cfg, params, raw = setup
     ws = serving.build_weight_store(
-        params, cfg, {2: (2.0, 6), 6: (16.0, 6)},
-        spec=serving.ServingQuantSpec(pack_planes=True))
+        params, cfg, {2: (2.0, 6), 6: (16.0, 6)}, pack_planes=True)
     spec = cfg.conv_stem[0]
     outs = {}
     for rung, view in ws.views.items():
@@ -124,8 +123,8 @@ def test_zero_padding_is_exact_not_approximate(setup):
     spec = cfg.conv_stem[0]
     if spec.ph == 0 and spec.pw == 0:
         pytest.skip("first stem layer of this arch is unpadded")
-    sp = serving.quantize_params_for_serving(
-        params, cfg, spec=serving.ServingQuantSpec(r=4.0, act_bits=6))
+    sp = serving.quantize_params_for_serving(params, cfg, r=4.0,
+                                             act_bits=6)
     p = sp["conv_stem"]["s0"]
     y = dispatch.serving_conv(raw, p, spec, "ref")
     xpad = pann_conv.pad_nhwc(raw, spec.ph, spec.pw)

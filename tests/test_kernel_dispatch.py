@@ -91,8 +91,7 @@ def test_every_module_role_bit_identical(arch, expect):
     cfg = _cfg(arch)
     params = MD.init_params(jax.random.PRNGKey(0), cfg)
     qp = serving.quantize_params_for_serving(
-        params, cfg, r=3.0, act_bits=6, pack_planes=True,
-        plane_count=serving.LADDER_PLANE_COUNT)
+        params, cfg, r=3.0, act_bits=6, pack_planes=True)
     modules = _quantized_modules(qp)
     assert expect <= set(modules), sorted(modules)
     for role, leaf in sorted(modules.items()):
@@ -180,21 +179,35 @@ def test_packed_without_planes_is_a_build_error():
 
 
 def test_variant_cache_pins_plane_count_across_rungs():
+    """Every rung view of a packed store holds its planes at
+    LADDER_PLANE_COUNT with one shape per module, so rungs share avals."""
     cfg = _cfg()
     params = MD.init_params(jax.random.PRNGKey(0), cfg)
-    with pytest.raises(ValueError, match="plane_count"):
-        serving.build_variant_cache(params, cfg, {2: 1.1, 4: 3.7},
+    ws = serving.build_weight_store(params, cfg, {2: 1.1, 4: 3.7},
                                     pack_planes=True)
+    shapes = {}
+    for rung, view in ws.views.items():
+        for path, leaf in jax.tree_util.tree_leaves_with_path(view):
+            key = getattr(path[-1], "key", "")
+            if key in dispatch.PLANE_KEYS:
+                # the plane axis sits behind the scan-stacked dims
+                assert leaf.shape[-3] == serving.LADDER_PLANE_COUNT, (
+                    rung, jax.tree_util.keystr(path), leaf.shape)
+                shapes.setdefault(jax.tree_util.keystr(path),
+                                  set()).add(leaf.shape)
+    assert shapes and all(len(v) == 1 for v in shapes.values()), shapes
 
 
 def test_legacy_backend_none_is_unchanged():
-    """backend=None must stay bit-exact with the pre-dispatch float path."""
+    """An artifact under kernel_backend=None runs the 'ref' integer path:
+    apply_linear equals dispatch.serving_linear(..., 'ref') bit for bit,
+    with a bias and without act_bits."""
     leaf = _leaf(48, 32, act_bits=None, bias=True)
+    assert "act_n" not in leaf and "b" in leaf
     x = jnp.asarray(RNG.standard_normal((3, 48)), jnp.float32)
     y = L.apply_linear(x, leaf, None, backend=None)
-    w = (leaf["w_q"].astype(jnp.float32) * leaf["w_scale"]).astype(x.dtype)
-    np.testing.assert_array_equal(np.asarray(y),
-                                  np.asarray(x @ w + leaf["b"]))
+    np.testing.assert_array_equal(
+        np.asarray(y), np.asarray(dispatch.serving_linear(x, leaf, "ref")))
 
 
 @pytest.mark.parametrize("allocation", ["uniform", "layerwise"])

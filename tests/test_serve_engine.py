@@ -1,4 +1,4 @@
-"""repro.serve_engine: ladder planning, variant cache, per-request rung
+"""repro.serve_engine: ladder planning, rung views, per-request rung
 selection, mid-stream rung switching, and the no-recompilation claim."""
 import dataclasses
 
@@ -11,8 +11,7 @@ from repro import configs
 from repro.configs.base import QuantConfig
 from repro.core import planner
 from repro.models import model as MD
-from repro.models.serving import (build_variant_cache,
-                                  quantize_params_for_serving)
+from repro.models.serving import quantize_params_for_serving
 from repro.serve_engine import (Request, Scheduler, ServeEngine, build_ladder,
                                 select_rung)
 
@@ -68,23 +67,28 @@ def test_ladder_matches_equal_power_budget():
 # Variant cache
 # ---------------------------------------------------------------------------
 
+def _without_plane_shift(tree):
+    return [(p, leaf) for p, leaf in jax.tree_util.tree_leaves_with_path(tree)
+            if getattr(p[-1], "key", "") != "plane_shift"]
+
+
 def test_variant_cache_bit_exact(setup):
+    """The engine's top-rung view (the max budget: no plane dropped) is
+    the single-point artifact at that point, leaf for leaf."""
     cfg, params = setup
-    ladder = build_ladder(LADDER_BITS, d=float(cfg.d_model))
-    cache = build_variant_cache(params, cfg,
-                                {op.bits: (op.r, op.b_x_tilde)
-                                 for op in ladder})
-    assert sorted(cache) == sorted(op.bits for op in ladder)
-    for op in ladder:
-        direct = quantize_params_for_serving(params, cfg, r=op.r,
-                                             act_bits=op.b_x_tilde)
-        flat_c = jax.tree_util.tree_leaves_with_path(cache[op.bits])
-        flat_d = jax.tree_util.tree_leaves_with_path(direct)
-        assert len(flat_c) == len(flat_d)
-        for (pc, lc), (pd, ld) in zip(flat_c, flat_d):
-            assert pc == pd
-            assert lc.dtype == ld.dtype
-            np.testing.assert_array_equal(np.asarray(lc), np.asarray(ld))
+    eng = ServeEngine(cfg, params, ladder_bits=LADDER_BITS, max_batch=2,
+                      max_len=28, backend="ref")
+    assert sorted(eng.variants) == sorted(op.bits for op in eng.ladder)
+    top = max(eng.ladder, key=lambda op: op.r)
+    direct = quantize_params_for_serving(params, cfg, r=top.r,
+                                         act_bits=top.b_x_tilde)
+    flat_v = _without_plane_shift(eng.variants[top.bits])
+    flat_d = jax.tree_util.tree_leaves_with_path(direct)
+    assert len(flat_v) == len(flat_d)
+    for (pv, lv), (pd, ld) in zip(flat_v, flat_d):
+        assert pv == pd
+        assert lv.dtype == ld.dtype
+        np.testing.assert_array_equal(np.asarray(lv), np.asarray(ld))
 
 
 def test_variants_share_pytree_structure(engine):
@@ -352,23 +356,27 @@ def test_variant_cache_mesh_sharded():
         from repro.configs.base import QuantConfig
         from repro.launch.mesh import make_mesh
         from repro.models import model as MD
-        from repro.models.serving import (build_variant_cache,
+        from repro.models.serving import (build_weight_store,
                                           quantize_params_for_serving)
 
         cfg = configs.reduced(configs.get_config("llama3-8b"))
         cfg = dataclasses.replace(cfg, quant=QuantConfig(mode="none"))
         params = MD.init_params(jax.random.PRNGKey(0), cfg)
         mesh = make_mesh((2, 4), ("data", "model"))
-        cache = build_variant_cache(params, cfg, {2: (2.83, 3)}, mesh=mesh)
+        ws = build_weight_store(params, cfg, {2: (2.83, 3)}, mesh=mesh)
         direct = quantize_params_for_serving(params, cfg, r=2.83, act_bits=3)
 
         n_wq = n_wq_sharded = 0
         exact = True
         repl_ok = True
-        for (path, leaf), (_, ref) in zip(
-                jax.tree_util.tree_leaves_with_path(cache[2]),
-                jax.tree_util.tree_leaves_with_path(direct)):
+        view = [(p, x) for p, x in
+                jax.tree_util.tree_leaves_with_path(ws.views[2])
+                if getattr(p[-1], "key", "") != "plane_shift"]
+        flat_d = jax.tree_util.tree_leaves_with_path(direct)
+        exact &= len(view) == len(flat_d)
+        for (path, leaf), (path_d, ref) in zip(view, flat_d):
             key = getattr(path[-1], "key", "")
+            exact &= path == path_d
             exact &= bool(np.array_equal(np.asarray(leaf), np.asarray(ref)))
             if key == "w_q":
                 n_wq += 1
