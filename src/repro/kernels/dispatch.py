@@ -65,6 +65,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import quant
 from repro.core.pann import bitplane_decompose, masked_codes
@@ -549,9 +550,12 @@ def decode_attention(q: Array, kv, backend, *, num_kv_heads: int,
 
     ``q``: (B, H, hd) fp queries of the current token (RoPE applied).
     ``kv``: a quantized cache, duck-typed — any object with ``k_planes`` /
-    ``v_planes`` (B, P, S, K, hd//8) uint8, ``k_s``/``k_z``/``v_s``/``v_z``
-    (B, S) f32 and scalar ``length`` (``models.attention.QuantKVCache``; no
-    models import here, same reason serving_linear takes a plain dict).
+    ``v_planes`` (B, P, S, K * hd//8) uint8 in the attention kernel's
+    layout (``kernels.ref.cache_planes``), which the kernel reads as it
+    lies,
+    ``k_s``/``k_z``/``v_s``/``v_z`` (B, S) f32 and scalar ``length``
+    (``models.attention.QuantKVCache``; no models import here, same reason
+    serving_linear takes a plain dict).
 
     Queries are affine-quantized per-tensor at the kernels' half-range
     ceiling (q is transient — the cache codes are the power knob, DESIGN.md
@@ -595,6 +599,31 @@ def decode_attention(q: Array, kv, backend, *, num_kv_heads: int,
                                         softcap=softcap)
     # exit barrier: the _dispatch_rows contract
     return jax.lax.optimization_barrier(out).reshape(b, h, hd)
+
+
+def _is_quant_cache(node) -> bool:
+    return hasattr(node, "k_planes") and hasattr(node, "k_s")
+
+
+def kv_caches(state) -> list[tuple[int, int]]:
+    """(attention calls, cached positions) of each quantized KV cache in a
+    decode ``state``, from shapes alone: a cache leaf stacked over layers
+    or call sites serves one call per stacked slice."""
+    return [(int(np.prod(n.k_s.shape[:-2])), n.k_s.shape[-1])
+            for n in jax.tree_util.tree_leaves(state, is_leaf=_is_quant_cache)
+            if _is_quant_cache(n)]
+
+
+def kv_block_traffic(caches, pos: int) -> tuple[int, int] | None:
+    """(read, held): the 128-position blocks that one decode step's
+    attention calls over ``caches`` (:func:`kv_caches`) read at cache
+    position ``pos`` (``pann_attention.live_blocks``, the kernel's own
+    rule), and the blocks those caches hold. None without a cache."""
+    if not caches:
+        return None
+    read = sum(n * _pa.live_blocks(int(pos), s) for n, s in caches)
+    held = sum(n * _pa.live_blocks(s - 1, s) for n, s in caches)
+    return read, held
 
 
 def wkv_decode(r: Array, k: Array, v: Array, w: Array, u: Array,

@@ -112,15 +112,20 @@ def _bit_transpose(words: list) -> list:
     return w
 
 
+def plane_codes(planes: Array) -> list:
+    """(P, 32, C) uint8 rows of P <= 8 packed bit-planes -> 8 (8, C) int32
+    words whose bytes are the codes sum_p 2^p plane_p: byte b of row s of
+    word j is the code of packed row 4s + b, bit j. Shared by the packed
+    matmul and the cache attention kernel."""
+    return _bit_transpose([pltpu.bitcast(planes[p], jnp.int32)
+                           for p in range(planes.shape[0])])
+
+
 def _group_words(pos: Array, neg: Array, keep) -> Array:
     """One 256-row K group: (P, 32, C) uint8 pos/neg plane rows -> (64, C)
     int32 words of signed int8 codes, 8 word rows per bit j, j-major.
     ``keep`` (int32 scalar or None) masks the dead planes' bits."""
-    mags = []
-    for planes in (pos, neg):
-        words = [pltpu.bitcast(planes[p], jnp.int32)
-                 for p in range(planes.shape[0])]
-        mags.append(_bit_transpose(words))
+    mags = [plane_codes(pos), plane_codes(neg)]
     out = []
     for a, n in zip(*mags):
         if keep is not None:

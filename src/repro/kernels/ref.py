@@ -63,6 +63,10 @@ MAX_CACHE_LEN = (1 << 31) // (1 << 15) - 1
 
 _CACHE_NEG_INF = -1e30   # matches models.attention.NEG_INF
 
+# The attention kernel reads the cache's positions in blocks of this many,
+# and only the blocks that hold a live position.
+CACHE_BLOCK = 128
+
 
 def pack_cache_codes(codes: Array, n_planes: int = CACHE_PLANES) -> Array:
     """Pack unsigned integer codes (..., d) in [0, 2^n_planes) into
@@ -94,6 +98,25 @@ def unpack_cache_codes(packed: Array) -> Array:
     return jnp.sum(bits * weights, axis=0)
 
 
+def cache_planes(codes: Array, n_planes: int = CACHE_PLANES) -> Array:
+    """Codes (B, S, K, hd) -> the cache's planes (B, P, S, K * hd/8)
+    uint8: per batch row and plane, one position per row, column
+    k * hd/8 + j holding byte j of KV head k's packed codes. Row-major,
+    this is the layout the TPU keeps for the array when K * hd/8 fills
+    whole 128-byte lane tiles, whatever S is."""
+    packed = pack_cache_codes(codes, n_planes)            # (P, B, S, K, d8)
+    p, b, s, k, d8 = packed.shape
+    return jnp.swapaxes(packed, 0, 1).reshape(b, p, s, k * d8)
+
+
+def cache_codes(planes: Array, kh: int) -> Array:
+    """Inverse of :func:`cache_planes`: (B, P, S, K * hd/8) uint8 with
+    ``kh`` KV heads -> (B, S, K, hd) int32."""
+    b, p, s, cols = planes.shape
+    x = planes.reshape(b, p, s, kh, cols // kh)
+    return unpack_cache_codes(jnp.swapaxes(x, 0, 1))
+
+
 def decode_attention_ref(qq: Array, q_z: Array, q_scale: Array,
                          k_planes: Array, k_s: Array, k_z: Array,
                          v_planes: Array, v_s: Array, v_z: Array,
@@ -104,8 +127,9 @@ def decode_attention_ref(qq: Array, q_z: Array, q_scale: Array,
 
     Shapes: qq (B, K, G, hd) int32 affine q codes (zero point ``q_z``,
     scalar int32); ``q_scale`` = s_q * hd**-0.5, scalar fp32; k_planes /
-    v_planes (B, P, S, K, hd//8) uint8; k_s/k_z/v_s/v_z (B, S) fp32
-    per-position quantizer rows (z integer-valued); pos () or (B,) int32.
+    v_planes (B, P, S, K * hd//8) uint8 in the layout of
+    :func:`cache_planes`; k_s/k_z/v_s/v_z (B, S) fp32 per-position
+    quantizer rows (z integer-valued); pos () or (B,) int32.
 
     The integer passes are EXACT (both zero points corrected inside int32;
     probabilities re-quantized at ``prob_scale``); the fp32 epilogue is the
@@ -114,8 +138,8 @@ def decode_attention_ref(qq: Array, q_z: Array, q_scale: Array,
     """
     b, kh, g, hd = qq.shape
     s = k_planes.shape[2]
-    kq = unpack_cache_codes(jnp.moveaxis(k_planes, 1, 0))   # (B, S, K, hd)
-    vq = unpack_cache_codes(jnp.moveaxis(v_planes, 1, 0))
+    kq = cache_codes(k_planes, kh)                          # (B, S, K, hd)
+    vq = cache_codes(v_planes, kh)
     qq = qq.astype(jnp.int32)
     qz = jnp.asarray(q_z, jnp.int32)
     kz = jnp.round(k_z).astype(jnp.int32)                   # (B, S)

@@ -71,20 +71,22 @@ class QuantKVCache(NamedTuple):
     """Power-aware KV cache: K/V as packed bit-plane affine codes.
 
     Codes are unsigned affine (``core.quant.affine_encode``), bit-plane
-    decomposed and packed 8/byte along head_dim (``kernels.ref.
-    pack_cache_codes``); the plane axis sits behind the batch/scan dims and
-    is pinned at ``kernels.ref.CACHE_PLANES`` whatever the rung's cache
-    bits, so every cache rung shares ONE pytree structure and one jitted
-    decode step (the ladder invariant). Quantizer (s, z) are per position —
-    dynamic ranges vary per token; frozen (calibrated) ranges broadcast one
-    scalar — with z integer-valued f32 (docs/kv_cache.md).
+    decomposed and packed 8/byte along head_dim, in the attention kernel's
+    own layout (``kernels.ref.cache_planes``: one position per row, S the
+    ``max_len``), so the kernel reads the cache where it lies. The plane
+    axis sits behind the batch/scan dims and is pinned at
+    ``kernels.ref.CACHE_PLANES`` whatever the rung's cache bits, so every
+    cache rung shares ONE pytree structure and one jitted decode step (the
+    ladder invariant). Quantizer (s, z) are per position — dynamic ranges
+    vary per token; frozen (calibrated) ranges broadcast one scalar — with
+    z integer-valued f32 (docs/kv_cache.md).
     """
-    k_planes: Array   # (B, P, S_max, K, hd//8) uint8
-    v_planes: Array   # (B, P, S_max, K, hd//8) uint8
-    k_s: Array        # (B, S_max) f32 per-position K scales
-    k_z: Array        # (B, S_max) f32 per-position K zero points (integer)
-    v_s: Array        # (B, S_max) f32
-    v_z: Array        # (B, S_max) f32
+    k_planes: Array   # (B, P, S, K * hd//8) uint8
+    v_planes: Array   # (B, P, S, K * hd//8) uint8
+    k_s: Array        # (B, S) f32 per-position K scales
+    k_z: Array        # (B, S) f32 per-position K zero points (integer)
+    v_s: Array        # (B, S) f32
+    v_z: Array        # (B, S) f32
     length: Array     # () int32 — tokens currently cached
 
 
@@ -242,8 +244,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
         assert hd % 8 == 0, (
             f"quantized KV cache packs 8 codes/byte along head_dim; "
             f"head_dim={hd} is not a multiple of 8")
-        shape = (batch, KREF.CACHE_PLANES, max_len, cfg.num_kv_heads,
-                 hd // 8)
+        shape = (batch, KREF.CACHE_PLANES, max_len,
+                 cfg.num_kv_heads * hd // 8)
         row = jnp.zeros((batch, max_len), jnp.float32)
         return QuantKVCache(k_planes=jnp.zeros(shape, jnp.uint8),
                             v_planes=jnp.zeros(shape, jnp.uint8),
@@ -344,11 +346,9 @@ def _cache_write(planes: Array, s_row: Array, z_row: Array, new: Array,
     codes = quant.affine_encode(new.astype(jnp.float32),
                                 s[:, None, None, None],
                                 z[:, None, None, None], n_lvl)
-    codes = codes[:, 0].astype(jnp.int32)                  # (B, K, hd)
-    tok = jnp.moveaxis(KREF.pack_cache_codes(codes), 0, 1)  # (B, P, K, d8)
+    tok = KREF.cache_planes(codes.astype(jnp.int32))       # (B, P, 1, K*d8)
     sel = jnp.arange(s_max) == pos
-    planes = jnp.where(sel[None, None, :, None, None],
-                       tok[:, :, None, :, :], planes)
+    planes = jnp.where(sel[:, None], tok, planes)
     s_row = jnp.where(sel[None, :], s[:, None], s_row)
     z_row = jnp.where(sel[None, :], z[:, None], z_row)
     return planes, s_row, z_row

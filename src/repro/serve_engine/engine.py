@@ -25,6 +25,11 @@ jitted step ``decode_step`` (module ``jit_decode_step``) and scopes inside it
 holds, and ``plane_bytes_in_place`` / ``plane_bytes_copied``, the packed
 planes one decode step reads from the weight store where they lie and
 through a sliced or padded copy (None until the step is traced).
+``kv_blocks_read`` / ``kv_blocks_cached`` sum, over decode steps and
+attention calls, the 128-position blocks of the quantized KV cache that
+the attention kernel reads (those holding a cached position) and the
+blocks the cache holds; None for a model without a quantized cache and
+before the first decode step.
 """
 from __future__ import annotations
 
@@ -235,6 +240,11 @@ class ServeEngine:
         self.decode_steps = 0         # step_lane calls that ran a step
         self.prefill_steps = 0        # teacher-forced prompt positions
         self.live_rows = 0            # decode-step rows still wanting a token
+        # 128-position cache blocks the attention calls read and hold,
+        # tallied from each lane's length (``dispatch.kv_block_traffic``)
+        self.kv_blocks_read: Optional[int] = None
+        self.kv_blocks_cached: Optional[int] = None
+        self._kv_caches: Optional[list] = None   # dispatch.kv_caches memo
         self._last_step_bits: Optional[int] = None
         self._macs_by_ctx: dict[int, Any] = {}   # macs_per_token memo
         self.recurrent_state_bytes = self._recurrent_state_bytes()
@@ -255,6 +265,16 @@ class ServeEngine:
         return int(sum(a.size * a.dtype.itemsize for n in nodes
                        if isinstance(n, kinds)
                        for a in jax.tree_util.tree_leaves(n)))
+
+    def _count_kv_blocks(self, state, pos: int) -> None:
+        """Add one decode step at cache position ``pos`` to the KV block
+        counters (the caches' shapes are read from ``state`` once)."""
+        if self._kv_caches is None:
+            self._kv_caches = dispatch.kv_caches(state)
+        traffic = dispatch.kv_block_traffic(self._kv_caches, pos)
+        if traffic is not None:
+            self.kv_blocks_read = (self.kv_blocks_read or 0) + traffic[0]
+            self.kv_blocks_cached = (self.kv_blocks_cached or 0) + traffic[1]
 
     # -- offline autotuning -------------------------------------------------
 
@@ -423,6 +443,9 @@ class ServeEngine:
                 self.live_rows += sum(r.max_new_tokens > have
                                       for r in wave.requests)
                 self.decode_steps += 1
+                # this step writes and attends at the lane's length
+                self._count_kv_blocks(
+                    lane.state, wave.requests[0].prompt_len + have - 1)
                 with _span("serve.dispatch", wave):
                     logits, lane.state = self._run_step(
                         wave.rung.bits, lane.state, lane.tok)
@@ -602,4 +625,6 @@ class ServeEngine:
             "recurrent_state_bytes": self.recurrent_state_bytes,
             "plane_bytes_in_place": self.plane_bytes_in_place,
             "plane_bytes_copied": self.plane_bytes_copied,
+            "kv_blocks_read": self.kv_blocks_read,
+            "kv_blocks_cached": self.kv_blocks_cached,
         }

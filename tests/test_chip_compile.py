@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import dispatch
 from repro.kernels import pann_attention as pa
+from repro.kernels import ref
 
 PLANES = 7              # dispatch.INT8_PLANES: the ladder's plane count
 ROWS = 4                # decode batch: one row per request
@@ -107,10 +108,11 @@ def test_fused_matmul_compiles(spec, k, n):
 
 def test_decode_attention_compiles(spec):
     # the shared attention block: 32 heads (kv 32), head_dim 64, a cache
-    # of prompt 32 + 16 generated positions
+    # of prompt 32 + 16 generated positions, less than one 128-position
+    # block and off the 32-row tile
     b, kh, g, hd, s = ROWS, 32, 1, 64, 48
     f32 = spec((), jnp.float32)
-    planes = spec((b, PLANES, s, kh, hd // 8), jnp.uint8)
+    planes = spec((b, PLANES, s, kh * hd // 8), jnp.uint8)
     row = spec((b, s), jnp.float32)
 
     def f(*args):
@@ -120,6 +122,69 @@ def test_decode_attention_compiles(spec):
                           planes, row, row, planes, row, row,
                           spec((), jnp.int32), f32, f32)
     assert "tpu_custom_call" in text
+
+
+# (batch, kv heads, group, head_dim, max_len): zamba2-1.2b's cell (16 rows,
+# 32 heads of 128, prompt 32 + up to 256 generated), GQA of llama3-8b and
+# gemma2-9b, qwen1.5-4b's 20 heads (320 packed columns, off the lane
+# tiles), and a long cache that leaves eight heads a cell
+ATTENTION_SHAPES = [(16, 32, 1, 128, 288), (16, 8, 4, 128, 288),
+                    (4, 8, 2, 256, 512), (16, 20, 1, 128, 288),
+                    (2, 32, 1, 128, 4096)]
+
+
+@pytest.mark.parametrize("b,kh,g,hd,max_len", ATTENTION_SHAPES)
+def test_decode_attention_reads_the_cache_in_place(spec, monkeypatch, b, kh,
+                                                   g, hd, max_len):
+    """``dispatch.decode_attention`` on a cache in its stored layout: the
+    compiled program is one kernel call, and no transpose, copy, pad,
+    slice or fusion takes or makes a uint8 array of the cache's size —
+    the kernel reads the cache where it lies. (An asynchronous
+    ``copy-start`` of a small cache into fast memory is the compiler's
+    prefetch, not a relayout.)"""
+    from collections import namedtuple
+    monkeypatch.setattr(dispatch.ops, "on_tpu", lambda: True)
+    s = max_len
+    cache = (b, PLANES, s, kh * hd // 8)
+    kv = namedtuple("KV", "k_planes v_planes k_s k_z v_s v_z length")(
+        spec(cache, jnp.uint8), spec(cache, jnp.uint8),
+        *[spec((b, s), jnp.float32)] * 4, spec((), jnp.int32))
+
+    def f(q, kv, k_nlvl, v_nlvl):
+        return dispatch.decode_attention(q, kv, "packed", num_kv_heads=kh,
+                                         k_nlvl=k_nlvl, v_nlvl=v_nlvl)
+
+    f32 = spec((), jnp.float32)
+    text = _compiled_text(f, spec((b, kh * g, hd), jnp.float32), kv, f32,
+                          f32)
+    assert text.count("tpu_custom_call") == 1
+    shape = "u8[%s]" % ",".join(map(str, cache))
+    assert shape in text
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if (m := _INSTRUCTION.match(line)) and shape in line
+              and m.group(2) in _RELAYOUTS]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("b,g,hd,max_len", [(4, 8, 128, 288),
+                                            (2, 4, 64, 48)])
+def test_decode_attention_compiles_for_one_kv_head(spec, b, g, hd,
+                                                    max_len):
+    """MQA: one KV head's hd/8 packed columns fill no lane tile, and the
+    kernel copies whole tiles. (The TPU keeps so narrow a cache with its
+    positions on lanes, so XLA relayouts it for the kernel: MQA is not
+    read in place.)"""
+    f32 = spec((), jnp.float32)
+    planes = spec((b, PLANES, max_len, hd // 8), jnp.uint8)
+    row = spec((b, max_len), jnp.float32)
+
+    def f(*args):
+        return pa.decode_attention(*args, interpret=False)
+
+    text = _compiled_text(f, spec((b, 1, g, hd), jnp.int32), f32, f32,
+                          planes, row, row, planes, row, row,
+                          spec((), jnp.int32), f32, f32)
+    assert text.count("tpu_custom_call") == 1
 
 
 @pytest.mark.parametrize("rows", [16, 3])
@@ -168,6 +233,8 @@ def test_ref_projection_leaves_through_its_barrier(spec):
 _INSTRUCTION = re.compile(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z-]+)\(")
 _COPYING = {"copy", "copy-start", "copy-done", "fusion", "slice",
             "dynamic-slice"}
+# the ops that would rewrite a cache in another layout or size
+_RELAYOUTS = {"copy", "transpose", "pad", "fusion", "slice", "dynamic-slice"}
 
 
 def _plane_copies(text: str, shapes) -> list[str]:

@@ -12,11 +12,14 @@ Plus property-based round-trip tests for the cache codec itself via the
 vendored hypothesis stub (tests/_hypothesis_stub.py; the real package wins
 when installed).
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.configs.base import ModelConfig
 from repro.core import quant
 from repro.kernels import dispatch
 from repro.kernels import pann_attention as pa
@@ -28,6 +31,11 @@ from repro.models import attention as ATT
 # helpers
 # ---------------------------------------------------------------------------
 
+# The TPU interpreter runs the kernel's copies and semaphores as the chip
+# does and fills memory that no copy wrote with garbage, as the chip leaves
+# it: a result that read a slot it never filled differs from the oracle.
+CHIP_LIKE = pltpu.InterpretParams(uninitialized_memory="nan")
+
 def _mk_cache_side(rng, b, s, kh, hd, bits, frozen=False):
     """Random packed planes + per-position quantizer rows for one side.
 
@@ -37,8 +45,7 @@ def _mk_cache_side(rng, b, s, kh, hd, bits, frozen=False):
     """
     cap = min((1 << bits) - 1, 127)
     codes = rng.integers(0, cap + 1, (b, s, kh, hd))
-    planes = ref.pack_cache_codes(jnp.asarray(codes, jnp.int32))
-    planes = jnp.moveaxis(planes, 0, 1)          # (B, P, S, K, hd//8)
+    planes = ref.cache_planes(jnp.asarray(codes, jnp.int32))
     if frozen:
         s_row = np.full((b, s), rng.uniform(0.01, 0.1), np.float32)
         z_row = np.full((b, s), float(rng.integers(0, cap + 1)), np.float32)
@@ -62,29 +69,47 @@ def _mk_inputs(seed, b, s, kh, g, hd, bits, frozen=False):
 # ref vs Pallas kernel: bit-identical fp32
 # ---------------------------------------------------------------------------
 
+def _positions(s):
+    """The first and last position, the middle, and both sides of every
+    128-position block edge below ``s``."""
+    return sorted({0, s // 2, s - 1} | {p for p in (127, 128, 255, 256)
+                                        if p < s})
+
+
 @pytest.mark.parametrize("b,s,kh,g,hd", [
     (1, 8, 1, 4, 8),       # MQA: one KV head, 4 query groups
     (2, 16, 2, 2, 16),     # GQA 2x2
     (2, 12, 4, 1, 8),      # MHA: group size 1
+    # S = 288 (three blocks, the last part-live): all 32 heads in one cell;
+    # two cells of 16 heads a row; 8 heads of G = 2; MQA with G = 4
+    (2, 288, 32, 1, 16),
+    (2, 288, 32, 4, 16),
+    (1, 288, 8, 2, 32),
+    (1, 288, 1, 4, 16),
 ])
 @pytest.mark.parametrize("bits", [2, 4, 7])
 def test_kernel_matches_ref_bit_identical(b, s, kh, g, hd, bits):
     args = _mk_inputs(0, b, s, kh, g, hd, bits)
-    for pos in (0, s // 2, s - 1):
+    for pos in _positions(s):
         want = ref.decode_attention_ref(*args, jnp.int32(pos))
-        got = pa.decode_attention(*args, jnp.int32(pos), interpret=True)
+        got = pa.decode_attention(*args, jnp.int32(pos), interpret=CHIP_LIKE)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("window,softcap", [(None, 0.0), (5, 0.0),
-                                            (None, 30.0), (3, 20.0)])
-def test_kernel_matches_ref_window_softcap(window, softcap):
-    args = _mk_inputs(1, 2, 16, 2, 2, 8, 4)
-    for pos in (2, 9, 15):
-        want = ref.decode_attention_ref(*args, jnp.int32(pos),
-                                        window=window, softcap=softcap)
+                                            (None, 30.0), (3, 20.0),
+                                            (140, 0.0), (200, 25.0)])
+@pytest.mark.parametrize("s", [16, 288])
+def test_kernel_matches_ref_window_softcap(window, softcap, s):
+    # the oracle compiled, as the kernel is: XLA's CPU tanh rounds
+    # differently op by op than inside a compiled program
+    oracle = jax.jit(ref.decode_attention_ref,
+                     static_argnames=("window", "softcap"))
+    args = _mk_inputs(1, 2, s, 2, 2, 8, 4)
+    for pos in sorted({2, 9, 15} | set(_positions(s))):
+        want = oracle(*args, jnp.int32(pos), window=window, softcap=softcap)
         got = pa.decode_attention(*args, jnp.int32(pos), window=window,
-                                  softcap=softcap, interpret=True)
+                                  softcap=softcap, interpret=CHIP_LIKE)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -144,6 +169,15 @@ def test_rung_switch_zero_high_planes_parity():
         want = ref.decode_attention_ref(*args, jnp.int32(11))
         got = pa.decode_attention(*args, jnp.int32(11), interpret=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the 3-bit rung's kernel copies 3 planes of 7 and never the dead high
+    # planes' slots: what those hold (garbage, under CHIP_LIKE) must not
+    # reach the codes
+    pact = dispatch.cache_planes_active(jnp.float32(7))
+    for pos in (11, 15):
+        got = pa.decode_attention(*lo, jnp.int32(pos), pact, pact,
+                                  interpret=CHIP_LIKE)
+        want = ref.decode_attention_ref(*lo, jnp.int32(pos))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_dispatch_backend_parity():
@@ -168,12 +202,15 @@ def test_incremental_writes_match_batch_pack():
     — so a decode stream's cache state is re-derivable from the prefix
     (what makes the rung-switch replay bit-exact)."""
     rng = np.random.default_rng(6)
-    b, t, kh, hd, bits = 2, 5, 2, 8, 4
+    b, t, kh, hd, bits = 2, 5, 4, 16, 4
     n_lvl = jnp.float32((1 << bits) - 1)
     xs = rng.standard_normal((b, t, kh, hd)).astype(np.float32)
-    planes = jnp.zeros((b, ref.CACHE_PLANES, t, kh, hd // 8), jnp.uint8)
-    s_row = jnp.zeros((b, t), jnp.float32)
-    z_row = jnp.zeros((b, t), jnp.float32)
+    cfg = ModelConfig(name="kv", family="dense", num_layers=1,
+                      d_model=kh * hd, num_heads=kh, num_kv_heads=kh,
+                      d_ff=kh * hd, vocab_size=8, cache_bits=bits)
+    init = ATT.init_cache(cfg, b, t, jnp.float32)
+    planes, s_row, z_row = init.k_planes, init.k_s, init.k_z
+    assert planes.shape == (b, ref.CACHE_PLANES, t, kh * hd // 8)
     codes_all = []
     for i in range(t):
         new = jnp.asarray(xs[:, i:i + 1])
@@ -183,8 +220,10 @@ def test_incremental_writes_match_batch_pack():
         codes_all.append(quant.affine_encode(
             new, s[:, None, None, None], z[:, None, None, None], n_lvl))
     codes = jnp.concatenate(codes_all, axis=1).astype(jnp.int32)
-    direct = jnp.moveaxis(ref.pack_cache_codes(codes), 0, 1)
+    direct = ref.cache_planes(codes)
     np.testing.assert_array_equal(np.asarray(planes), np.asarray(direct))
+    np.testing.assert_array_equal(np.asarray(ref.cache_codes(planes, kh)),
+                                  np.asarray(codes))
 
 
 # ---------------------------------------------------------------------------

@@ -296,6 +296,20 @@ def test_engine_counts_the_recurrent_state():
         cfg.num_layers * per_layer
 
 
+def test_engine_counts_no_kv_blocks_without_a_kv_cache():
+    """Finch holds no KV cache: after decode steps the KV block counters
+    stay None."""
+    from repro.serve_engine import Request
+    cfg, params = _params()
+    eng = ServeEngine(cfg, params=params, ladder_bits=(4,), max_batch=B,
+                      max_len=T, backend="ref")
+    eng.generate([Request(uid=0, max_new_tokens=3, power_budget_bits=4,
+                          prompt=np.arange(2, dtype=np.int32))])
+    got = eng.describe()
+    assert got["decode_steps"] > 0
+    assert got["kv_blocks_read"] is None and got["kv_blocks_cached"] is None
+
+
 def test_param_count_is_the_published_block():
     """Every matrix of RWKV_Tmix_x060 / RWKV_CMix_x060 at the released
     checkpoint's shapes (RWKV/v6-Finch-1B6-HF): 24 x 55.4 M + the two

@@ -217,3 +217,29 @@ def test_engine_counts_the_plane_bytes_of_a_step(arch):
     assert want["in_place"] and want["copied"]
     assert (got["plane_bytes_in_place"], got["plane_bytes_copied"]) == \
         (want["in_place"], want["copied"])
+
+
+def test_engine_counts_the_kv_blocks_its_attention_reads(setup):
+    """A wave with prompt 3 and outputs 131 and 2 on a 200-position cache
+    (two 128-position blocks): its 130 decode steps write positions 3..132,
+    so each shared-attention call reads one block at positions up to 127
+    and two after; ``describe()`` sums exactly that, and the blocks held."""
+    cfg, params = setup
+    eng = ServeEngine(cfg, params, ladder_bits=(4,), max_batch=MAX_BATCH,
+                      max_len=200, cache_bits=4)
+    assert eng.describe()["kv_blocks_read"] is None
+    rng = np.random.default_rng(1)
+    eng.generate([Request(uid=i, max_new_tokens=n, power_budget_bits=4,
+                          prompt=rng.integers(0, cfg.vocab_size, 3)
+                          .astype(np.int32))
+                  for i, n in enumerate((131, 2))])
+    pattern, n_groups, n_tail = T.group_layout(cfg)
+    kinds = [s.kind for s in pattern] * n_groups + \
+        [pattern[i % len(pattern)].kind for i in range(n_tail)]
+    calls = kinds.count("mamba_attn")
+    positions = range(3, 3 + 130)
+    got = eng.describe()
+    assert calls and got["decode_steps"] == len(positions)
+    assert got["kv_blocks_read"] == calls * sum(p // 128 + 1
+                                                for p in positions)
+    assert got["kv_blocks_cached"] == calls * 2 * len(positions)
